@@ -25,6 +25,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_async, bench_coding, bench_compression,
                             bench_convergence, bench_filters, bench_p2p,
                             bench_roofline, bench_serving)

@@ -193,6 +193,7 @@ import numpy as np
 
 from repro.core.filters import dense as D
 from repro.core.flat import FlatPlan
+from repro.kernels.ref import sparse_mean_law, weighted_row_sum
 
 
 class AggregatorDeprecationWarning(DeprecationWarning):
@@ -274,10 +275,8 @@ def tree_dot(grads, vec_tree):
 
 def tree_weighted_sum(grads, w):
     """sum_i w_i * g_i per leaf."""
-    def leaf(l):
-        wl = w.astype(jnp.float32).reshape((-1,) + (1,) * (l.ndim - 1))
-        return jnp.sum(l.astype(jnp.float32) * wl, axis=0).astype(l.dtype)
-    return jax.tree.map(leaf, grads)
+    return jax.tree.map(lambda l: weighted_row_sum(l, w).astype(l.dtype),
+                        grads)
 
 
 def tree_where_agents(mask, a, b):
@@ -1340,7 +1339,7 @@ def _flat_masked_vec(spec, d, stack, mask, weights, state, qscale=None):
         return scaled(_arrived_coord_vec(spec, _flat_f32(stack), mask))
     wn = w / tot
     xf = _flat_f32(stack)
-    mean_sel = jnp.sum(xf * wn[:, None], axis=0).astype(stack.dtype)
+    mean_sel = weighted_row_sum(xf, wn).astype(stack.dtype)
     imputed = jnp.where(mask[:, None], stack, mean_sel[None])
     return scaled(_flat_sync_vec(spec, d, imputed, state))
 
@@ -2034,15 +2033,14 @@ def _cclip_flat(spec, stack, mask, weights, state, qscale=None):
 
     use_kernel = spec.impl == "pallas"
     if use_kernel:
-        from repro.kernels import clipped_weighted_sum, default_interpret
+        from repro.kernels import clipped_weighted_sum
         from repro.kernels.tiling import TILE_D
         use_kernel = P % TILE_D == 0
 
     def body(_, v):
         lam, diff = lam_of(v)
         if use_kernel:
-            return clipped_weighted_sum(lam, xf, v,
-                                        interpret=default_interpret())
+            return clipped_weighted_sum(lam, xf, v)
         return v + jnp.sum(diff * lam[:, None], axis=0)
 
     return jax.lax.fori_loop(0, iters, body, v0)
@@ -2095,16 +2093,6 @@ def _sparse_row_weights(n, mask, weights):
     return m if weights is None else weights.astype(jnp.float32) * m
 
 
-def _sparse_mean_law(xf, cw):
-    """agg_c = sum_i cw_ic x_ic / sum_i cw_ic, explicit 0 where the
-    denominator is 0 (nobody sent the coordinate — never an eps-scaled
-    garbage row).  The where-gate keeps 0 * non-finite == 0 exactly, so
-    dead-row garbage cannot leak through a zero weight."""
-    num = jnp.sum(jnp.where(cw > 0, xf, 0.0) * cw, axis=0)
-    den = jnp.sum(cw, axis=0)
-    return jnp.where(den > 0, num / jnp.where(den > 0, den, 1.0), 0.0)
-
-
 def _sparse_mean_flat(spec, stack, mask, weights, state, qscale=None):
     """sparse_mean on the (n, P) arena.  ``impl="pallas"`` runs the
     sparse kernel (repro.kernels.wsum) with sent-detection on the native
@@ -2127,8 +2115,7 @@ def _sparse_mean_flat(spec, stack, mask, weights, state, qscale=None):
         xf = dequantize_rows(stack, qscale)
     else:
         xf = _flat_f32(stack)
-    cw = (xf != 0).astype(jnp.float32) * w[:, None]
-    return _sparse_mean_law(xf, cw)
+    return sparse_mean_law(xf, w)
 
 
 @register_aggregator(
@@ -2171,10 +2158,8 @@ def sparse_mean(spec, grads, mask, weights, state):
         return jax.tree.unflatten(treedef, outs)
 
     def leaf(l):
-        xf = l.astype(jnp.float32)
-        wl = w.reshape((-1,) + (1,) * (l.ndim - 1))
-        cw = (xf != 0).astype(jnp.float32) * wl
-        return _sparse_mean_law(xf, cw).astype(l.dtype)
+        xf = l.reshape(n, -1).astype(jnp.float32)
+        return sparse_mean_law(xf, w).astype(l.dtype).reshape(l.shape[1:])
     return jax.tree.map(leaf, grads)
 
 

@@ -50,6 +50,14 @@ def pairwise_sq_dists(g):
     return jnp.where(jnp.eye(n, dtype=bool), 0.0, d2)
 
 
+def take_rows(g, idx):
+    """``g[idx]`` for a static-length index vector, as one dynamic slice
+    per row.  A row gather out of a model-wide (n, P) stack takes XLA's
+    TPU compiler minutes (158 s at P = 124.7M, compiled for v5e); the
+    slices compile in under a second and copy the same rows."""
+    return jnp.stack([g[idx[k]] for k in range(idx.shape[0])])
+
+
 def krum_scores(d2, f, mask=None, k=None):
     """Krum score s(i) = sum of distances to the k closest others
     (k defaults to the classic n - f - 2).
@@ -159,7 +167,7 @@ def multi_krum(g, f, m: int = 2):
     d2 = pairwise_sq_dists(g)
     s = krum_scores(d2, f)
     _, idx = jax.lax.top_k(-s, m)
-    return jnp.mean(g[idx], axis=0)
+    return jnp.mean(take_rows(g, idx), axis=0)
 
 
 @register("m_krum")
@@ -195,7 +203,7 @@ def mda(g, f):
     # enumeration order (see argmin_tiebreak)
     best = jnp.asarray(combos)[
         argmin_tiebreak(diam, jnp.sum(sub, axis=(1, 2)))]
-    return jnp.mean(g[best], axis=0)
+    return jnp.mean(take_rows(g, best), axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +299,7 @@ def cge(g, f, normalize: bool = True):
     n = g.shape[0]
     norms = jnp.linalg.norm(g, axis=-1)
     neg_top, idx = jax.lax.top_k(-norms, n - f)
-    out = jnp.sum(g[idx], axis=0)
+    out = jnp.sum(take_rows(g, idx), axis=0)
     return out / (n - f) if normalize else out
 
 
@@ -380,7 +388,7 @@ def zeno(g, f, server_grad=None, rho: float = 1e-3, lr: float = 1.0):
     n = g.shape[0]
     score = lr * (g @ server_grad) - rho * jnp.sum(jnp.square(g), axis=-1)
     _, idx = jax.lax.top_k(score, n - f)
-    return jnp.mean(g[idx], axis=0)
+    return jnp.mean(take_rows(g, idx), axis=0)
 
 
 # ---------------------------------------------------------------------------

@@ -153,19 +153,17 @@ def flat_draco_aggregate(x, r: int, tol: float = 1e-6, mask=None,
     The vote rides ``kernels.pairwise.gram`` (one MXU matmul per tile) and
     the application ``kernels.wsum.masked_weighted_sum`` (one-hot winner
     weights are non-negative, satisfying its precondition; non-winning
-    rows are where-zeroed, so Byzantine ±inf never leaks).  Columns are
-    zero-padded to the kernels' TILE_D multiple — zero columns change
-    neither the Gram nor the weighted sum — and the pad is sliced off."""
-    from repro.kernels.dispatch import default_interpret
+    rows are where-zeroed, so Byzantine ±inf never leaks).  In interpret
+    mode columns are zero-padded to the kernels' TILE_D multiple (zero
+    columns change neither the Gram nor the weighted sum) and the pad is
+    sliced off; compiled kernels tile a ragged last block in place."""
     from repro.kernels.ops import _pad_d
     from repro.kernels.pairwise import gram
     from repro.kernels.wsum import masked_weighted_sum
-    if interpret is None:
-        interpret = default_interpret()
     n, p = x.shape
     if groups is None:
         groups = coding_groups(n, r)
-    xp, _ = _pad_d(x)
+    xp, _ = _pad_d(x, interpret)
     w = coded_vote_weights(gram(xp, interpret=interpret), r, tol=tol,
                            mask=mask, groups=groups)
     m = jnp.ones((n,), bool) if mask is None else mask.astype(bool)
@@ -233,7 +231,6 @@ def tree_draco_aggregate(grads, r: int, tol: float = 1e-6, mask=None,
     elastic loops pass their bucket's (possibly ragged) table here."""
     from repro.core.aggregators import warn_once
     from repro.core.flat import FlatPlan
-    from repro.kernels.dispatch import default_interpret
     from repro.kernels.ops import _pad_d
     from repro.kernels.pairwise import gram
     from repro.kernels.wsum import masked_weighted_sum
@@ -261,7 +258,6 @@ def tree_draco_aggregate(grads, r: int, tol: float = 1e-6, mask=None,
         f"({', '.join(sorted(str(d) for d in by_dtype))}) decodes through "
         "per-dtype sub-arenas instead of one flat arena; set a uniform "
         "exchange dtype (e.g. agg_dtype) to restore the single-ravel path")
-    interpret = default_interpret()
     m = jnp.ones((n,), bool) if mask is None else mask.astype(bool)
     segs = {}
     total_gram = None
@@ -270,14 +266,13 @@ def tree_draco_aggregate(grads, r: int, tol: float = 1e-6, mask=None,
             [leaves[i].reshape(n, -1) for i in idxs], axis=1)
         segp, _ = _pad_d(seg)
         segs[dt] = (idxs, seg.shape[1], segp)
-        g = gram(segp, interpret=interpret)
+        g = gram(segp)
         total_gram = g if total_gram is None else total_gram + g
     w = coded_vote_weights(total_gram, r, tol=tol, mask=mask, groups=groups)
     out = [None] * len(leaves)
     for dt, (idxs, p, segp) in segs.items():
         vec = masked_weighted_sum(
-            w, segp, m, jnp.zeros((segp.shape[1],), jnp.float32),
-            interpret=interpret)[:p]
+            w, segp, m, jnp.zeros((segp.shape[1],), jnp.float32))[:p]
         off = 0
         for i in idxs:
             size = leaves[i][0].size

@@ -1,4 +1,4 @@
-from repro.kernels.dispatch import (default_interpret, pallas_aggregate,
+from repro.kernels.dispatch import (pallas_aggregate,
                                     pallas_masked_aggregate,
                                     pallas_masked_supported,
                                     pallas_scaled_aggregate,
@@ -14,6 +14,7 @@ from repro.kernels.ops import (kernel_bulyan, kernel_bulyan_masked,
                                kernel_multi_krum_masked,
                                kernel_pairwise_sq_dists,
                                kernel_trimmed_mean)
+from repro.kernels.tiling import default_interpret
 from repro.kernels.wsum import clipped_weighted_sum
 
 __all__ = ["kernel_coordinate_median", "kernel_trimmed_mean", "kernel_krum",

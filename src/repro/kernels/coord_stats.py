@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import TILE_D, block_d
+from repro.kernels.tiling import block_d, resolve_interpret
 
 
 def _sort_network(x):
@@ -50,15 +50,14 @@ def _coord_sort_kernel(g_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def coord_sort(g, *, interpret: bool = True):
-    """g: (n, d) -> sorted-per-coordinate (n, d) fp32.  d must be a multiple
-    of TILE_D (ops.py pads)."""
+def coord_sort(g, *, interpret: bool | None = None):
+    """g: (n, d) -> sorted-per-coordinate (n, d) fp32."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     return pl.pallas_call(
         _coord_sort_kernel,
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[pl.BlockSpec((n, w), lambda i: (0, i))],
         out_specs=pl.BlockSpec((n, w), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
@@ -92,17 +91,17 @@ def _coord_stat_kernel(g_ref, out_ref, *, stat, b, exact):
 
 
 @functools.partial(jax.jit, static_argnames=("stat", "b", "interpret"))
-def coord_stat(g, stat: str, b: int = 0, *, interpret: bool = True):
+def coord_stat(g, stat: str, b: int = 0, *, interpret: bool | None = None):
     """g: (n, d) -> (d,) fp32 order statistic (``median`` |
     ``trimmed_mean`` with per-side trim ``b``), fused sort+reduce per tile:
-    the sorted stack never leaves VMEM.  d must be a multiple of TILE_D."""
+    the sorted stack never leaves VMEM."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_coord_stat_kernel, stat=stat, b=b,
                           exact=interpret),
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[pl.BlockSpec((n, w), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, w), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),

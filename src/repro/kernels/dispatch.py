@@ -17,10 +17,12 @@ bit-for-bit for the order-statistic and single-selection rules, selection-
 identical with ulp-level application rounding for averaged selections
 (CGE) — proven case by case in tests/test_kernels_parity.py.
 
-``interpret`` resolution: kernels compile to real Mosaic kernels on TPU
-backends and fall back to interpret mode (pure-jax evaluation of the SAME
-kernel bodies) everywhere else, so CPU CI runs the code path production
-runs — override per call for debugging.
+``interpret`` resolution (:func:`repro.kernels.tiling.default_interpret`):
+kernels compile to real Mosaic kernels on TPU backends and run in
+interpret mode (pure-jax evaluation of the SAME kernel bodies, one coarse
+block) everywhere else.  Interpret mode checks the arithmetic, not the
+compiled tiling or what Mosaic accepts: tests/test_tpu_compile.py compiles
+every kernel here for a described v5e.  Override per call for debugging.
 """
 from __future__ import annotations
 
@@ -43,17 +45,6 @@ from repro.kernels.ops import (_pad_d, kernel_bulyan, kernel_bulyan_masked,
 from repro.kernels.wsum import (scaled_sparse_masked_weighted_mean,
                                 sparse_masked_weighted_mean)
 
-_INTERPRET = None
-
-
-def default_interpret() -> bool:
-    """True (interpret mode) unless running on a real TPU backend."""
-    global _INTERPRET
-    if _INTERPRET is None:
-        _INTERPRET = jax.default_backend() != "tpu"
-    return _INTERPRET
-
-
 def _trim_b(n: int, f: int, hyper: dict) -> int:
     from repro.core.aggregators import trim_count          # lazy: no cycle
     return trim_count(n, f, hyper.get("beta"))
@@ -64,12 +55,12 @@ def _trim_b(n: int, f: int, hyper: dict) -> int:
 
 
 def _median(stack, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return coord_stat(gp, "median", interpret=interpret)[:d]
 
 
 def _trimmed_mean(stack, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     b = _trim_b(stack.shape[0], f, hyper)
     return coord_stat(gp, "trimmed_mean", b=b, interpret=interpret)[:d]
 
@@ -106,7 +97,7 @@ def _bulyan(stack, f, hyper, interpret):
 
 
 def _sign_sgd(stack, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return sign_vote(gp, interpret=interpret)[:d]
 
 
@@ -114,7 +105,7 @@ def _sparse_mean(stack, f, hyper, interpret):
     # plain = every row live with unit weight; padded columns are all-zero
     # (nobody "sent" them) and slice away
     n = stack.shape[0]
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     ones = jnp.ones((n,), jnp.float32)
     return sparse_masked_weighted_mean(gp, ones, ones,
                                        interpret=interpret)[:d]
@@ -153,13 +144,13 @@ FLAT_SELF_KERNELED = {"centered_clip"}
 
 
 def _masked_median(stack, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return masked_coord_stat(gp, mask, wn, "median",
                              interpret=interpret)[:d]
 
 
 def _masked_trimmed_mean(stack, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     b = _trim_b(stack.shape[0], f, hyper)
     return masked_coord_stat(gp, mask, wn, "trimmed_mean", b=b,
                              interpret=interpret)[:d]
@@ -196,7 +187,7 @@ def _masked_bulyan(stack, mask, wn, f, hyper, interpret):
 
 
 def _masked_sign_sgd(stack, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return masked_sign_vote(gp, mask, wn, interpret=interpret)[:d]
 
 
@@ -204,7 +195,7 @@ def _masked_sparse_mean(stack, mask, wn, f, hyper, interpret):
     # the wn slot carries RAW mask-folded row weights (dataset sizes), not
     # the normalized w/tot the imputing rules take — sparse_mean's law is
     # invariant under global weight scaling, so both conventions agree
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return sparse_masked_weighted_mean(gp, mask, wn,
                                        interpret=interpret)[:d]
 
@@ -232,12 +223,12 @@ PALLAS_MASKED_RULES = {
 
 
 def _scaled_median(stack, qs, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return scaled_coord_stat(gp, qs, "median", interpret=interpret)[:d]
 
 
 def _scaled_trimmed_mean(stack, qs, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     b = _trim_b(stack.shape[0], f, hyper)
     return scaled_coord_stat(gp, qs, "trimmed_mean", b=b,
                              interpret=interpret)[:d]
@@ -246,13 +237,13 @@ def _scaled_trimmed_mean(stack, qs, f, hyper, interpret):
 def _scaled_sign_sgd(stack, qs, f, hyper, interpret):
     # sign(code * scale) == sign(code): scales are strictly positive, so
     # the plain vote kernel reads the codes directly — zero dequant cost
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return sign_vote(gp, interpret=interpret)[:d]
 
 
 def _scaled_sparse_mean(stack, qs, f, hyper, interpret):
     n = stack.shape[0]
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     ones = jnp.ones((n,), jnp.float32)
     return scaled_sparse_masked_weighted_mean(gp, qs, ones, ones,
                                               interpret=interpret)[:d]
@@ -267,26 +258,26 @@ PALLAS_SCALED_RULES = {
 
 
 def _scaled_masked_median(stack, qs, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return scaled_masked_coord_stat(gp, qs, mask, wn, "median",
                                     interpret=interpret)[:d]
 
 
 def _scaled_masked_trimmed_mean(stack, qs, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     b = _trim_b(stack.shape[0], f, hyper)
     return scaled_masked_coord_stat(gp, qs, mask, wn, "trimmed_mean", b=b,
                                     interpret=interpret)[:d]
 
 
 def _scaled_masked_sign_sgd(stack, qs, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return scaled_masked_sign_vote(gp, qs, mask, wn,
                                    interpret=interpret)[:d]
 
 
 def _scaled_masked_sparse_mean(stack, qs, mask, wn, f, hyper, interpret):
-    gp, d = _pad_d(stack)
+    gp, d = _pad_d(stack, interpret)
     return scaled_sparse_masked_weighted_mean(gp, qs, mask, wn,
                                               interpret=interpret)[:d]
 
@@ -324,8 +315,7 @@ def pallas_aggregate(name: str, stack, f: int, hyper: tuple = (), *,
                      interpret: bool | None = None):
     """stack: fp32 (n, P) -> (P,) fp32 via the rule's Pallas kernels.
     ``hyper`` is the spec's sorted static hyper tuple."""
-    itp = default_interpret() if interpret is None else interpret
-    return PALLAS_RULES[name](stack, f, dict(hyper), itp)
+    return PALLAS_RULES[name](stack, f, dict(hyper), interpret)
 
 
 @functools.partial(jax.jit,
@@ -335,8 +325,8 @@ def pallas_masked_aggregate(name: str, stack, mask, wn, f: int,
                             interpret: bool | None = None):
     """Mean-imputed masked statistic; mask/wn are TRACED (n,) operands, so
     per-step fault masks never retrigger compilation."""
-    itp = default_interpret() if interpret is None else interpret
-    return PALLAS_MASKED_RULES[name](stack, mask, wn, f, dict(hyper), itp)
+    return PALLAS_MASKED_RULES[name](stack, mask, wn, f, dict(hyper),
+                                     interpret)
 
 
 @functools.partial(jax.jit,
@@ -346,8 +336,8 @@ def pallas_scaled_aggregate(name: str, stack, qscale, f: int,
                             interpret: bool | None = None):
     """stack: quantized (n, P) codes, qscale: (n,) fp32 per-row dequant
     scale -> (P,) fp32 aggregate, dequantization fused into the tiles."""
-    itp = default_interpret() if interpret is None else interpret
-    return PALLAS_SCALED_RULES[name](stack, qscale, f, dict(hyper), itp)
+    return PALLAS_SCALED_RULES[name](stack, qscale, f, dict(hyper),
+                                     interpret)
 
 
 @functools.partial(jax.jit,
@@ -358,6 +348,5 @@ def pallas_scaled_masked_aggregate(name: str, stack, qscale, mask, wn,
     """Masked variant of :func:`pallas_scaled_aggregate` — qscale, mask
     and wn are all TRACED (n,) operands (fault masks and per-step scales
     never retrigger compilation)."""
-    itp = default_interpret() if interpret is None else interpret
     return PALLAS_SCALED_MASKED_RULES[name](stack, qscale, mask, wn, f,
-                                            dict(hyper), itp)
+                                            dict(hyper), interpret)

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.coord_stats import _sort_network, stat_from_sorted
-from repro.kernels.tiling import TILE_D, block_d
+from repro.kernels.tiling import block_d, resolve_interpret
 
 
 def _masked_stat_kernel(g_ref, mask_ref, wn_ref, out_ref, *, stat, b,
@@ -59,16 +59,16 @@ def _sign_vote_kernel(g_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sign_vote(g, *, interpret: bool = True):
+def sign_vote(g, *, interpret: bool | None = None):
     """g: (n, d) any dtype (fp32 arena or int8/fp8 codes — sign is
     invariant under the positive per-row dequant scale) -> (d,) fp32
-    majority vote.  d must be a multiple of TILE_D."""
+    majority vote."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         _sign_vote_kernel,
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[pl.BlockSpec((n, w), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, w), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
@@ -87,15 +87,15 @@ def _masked_sign_vote_kernel(g_ref, mask_ref, wn_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_sign_vote(g, mask, wn, *, interpret: bool = True):
+def masked_sign_vote(g, mask, wn, *, interpret: bool | None = None):
     """Majority vote over the arrived rows only (the engine's masked law
     for the sign family), fused per tile."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         _masked_sign_vote_kernel,
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
@@ -131,17 +131,17 @@ def _scaled_stat_kernel(g_ref, sc_ref, out_ref, *, stat, b, exact):
 
 @functools.partial(jax.jit, static_argnames=("stat", "b", "interpret"))
 def scaled_coord_stat(g, scale, stat: str, b: int = 0, *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """g: (n, d) quantized codes, scale: (n,) fp32 per-row dequant scale
     -> (d,) fp32 order statistic over the dequantized stack, dequant fused
     into the sort tile."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_scaled_stat_kernel, stat=stat, b=b,
                           exact=interpret),
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
@@ -169,16 +169,16 @@ def _scaled_masked_stat_kernel(g_ref, sc_ref, mask_ref, wn_ref, out_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("stat", "b", "interpret"))
 def scaled_masked_coord_stat(g, scale, mask, wn, stat: str, b: int = 0, *,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Masked order statistic over a quantized arena: in-tile dequant,
     +inf sentinels for absent rows, fused sort + arrived-window reduce."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_scaled_masked_stat_kernel, stat=stat, b=b,
                           exact=interpret),
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
@@ -204,18 +204,18 @@ def _scaled_masked_sign_kernel(g_ref, sc_ref, mask_ref, wn_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def scaled_masked_sign_vote(g, scale, mask, wn, *, interpret: bool = True):
+def scaled_masked_sign_vote(g, scale, mask, wn, *, interpret: bool | None = None):
     """Masked majority vote over a quantized arena: arrived rows vote,
     absent rows cast none.  The per-row dequant scale is sign-neutral
     (scales are non-negative), but the dequant is kept so the kernel's
     arithmetic matches the engine's dequantized fp32 reference
     bit-for-bit."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         _scaled_masked_sign_kernel,
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),
@@ -233,17 +233,16 @@ def scaled_masked_sign_vote(g, scale, mask, wn, *, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("stat", "b", "interpret"))
 def masked_coord_stat(g, mask, wn, stat: str, b: int = 0, *,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """g: (n, d) any dtype, mask: (n,) {0,1} f32, wn: (n,) f32 normalized
-    weights -> (d,) fp32 statistic over the arrived rows.  d must be
-    a multiple of TILE_D (the dispatch layer pads)."""
+    weights -> (d,) fp32 statistic over the arrived rows."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_masked_stat_kernel, stat=stat, b=b,
                           exact=interpret),
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),

@@ -15,12 +15,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import TILE_D, block_d
+from repro.kernels.tiling import block_d, resolve_interpret
 
 
-def _gram_kernel(g_ref, out_ref):
+def _ragged(d: int, w: int):
+    """d when the last of the w-wide blocks is ragged, else None."""
+    return d if d % w else None
+
+
+def _valid_cols(x, i, d):
+    """Zero the columns of block i past the true width d: a ragged last
+    block reads undefined values there, which would enter the Gram sum
+    (d=None: the grid tiles d exactly)."""
+    if d is None:
+        return x
+    t = x.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + i * t
+    return jnp.where(col < d, x, 0.0)
+
+
+def _gram_kernel(g_ref, out_ref, *, d):
     i = pl.program_id(0)
-    x = g_ref[...].astype(jnp.float32)            # (n, T)
+    x = _valid_cols(g_ref[...].astype(jnp.float32), i, d)   # (n, T)
     part = jax.lax.dot_general(
         x, x, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (n, n) on the MXU
@@ -35,14 +51,14 @@ def _gram_kernel(g_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gram(g, *, interpret: bool = True):
-    """g: (n, d) -> (n, n) fp32 Gram.  d must be a multiple of TILE_D."""
+def gram(g, *, interpret: bool | None = None):
+    """g: (n, d) -> (n, n) fp32 Gram (any d)."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w = block_d(d, interpret)
     return pl.pallas_call(
-        _gram_kernel,
-        grid=(d // w,),
+        functools.partial(_gram_kernel, d=_ragged(d, w)),
+        grid=(pl.cdiv(d, w),),
         in_specs=[pl.BlockSpec((n, w), lambda i: (0, i))],
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
@@ -57,11 +73,11 @@ def imputed_mean(g, wn):
     to the tree-level engine's.  Output-sized (d,), so sharing it across
     the Gram / selection / application kernels keeps the path
     imputation-free (no (n, d) copy) while computing the mean once."""
-    return jnp.sum(g.astype(jnp.float32) * wn.astype(jnp.float32)[:, None],
-                   axis=0).astype(g.dtype)
+    from repro.kernels.ref import weighted_row_sum
+    return weighted_row_sum(g, wn).astype(g.dtype)
 
 
-def _masked_gram_kernel(g_ref, mask_ref, mean_ref, out_ref):
+def _masked_gram_kernel(g_ref, mask_ref, mean_ref, out_ref, *, d):
     """Gram of the MEAN-IMPUTED stack, imputation fused into the tile
     (the kernels/masked.py trick applied to the pairwise path): absent
     rows are replaced inside the tile by the precomputed (T,) mean slice,
@@ -72,6 +88,7 @@ def _masked_gram_kernel(g_ref, mask_ref, mean_ref, out_ref):
     m = mask_ref[...][0]                             # (n,) f32, 1 = arrived
     mean = mean_ref[...][0]                          # (T,) native dtype
     xi = jnp.where(m[:, None] > 0.5, x, mean[None]).astype(jnp.float32)
+    xi = _valid_cols(xi, i, d)
     part = jax.lax.dot_general(
         xi, xi, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (n, n) on the MXU
@@ -86,20 +103,19 @@ def _masked_gram_kernel(g_ref, mask_ref, mean_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_gram(g, mask, wn, mean=None, *, interpret: bool = True):
+def masked_gram(g, mask, wn, mean=None, *, interpret: bool | None = None):
     """g: (n, d) any dtype, mask: (n,) {0,1} f32, wn: (n,) f32 normalized
     weights -> (n, n) fp32 Gram of the mean-imputed stack.  ``mean``: the
     (d,) :func:`imputed_mean` (computed here when None — pass it in to
-    share one mean across a kernel pipeline).  d must be a multiple of
-    TILE_D (the dispatch layer pads)."""
+    share one mean across a kernel pipeline)."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     if mean is None:
         mean = imputed_mean(g, wn)
     w = block_d(d, interpret)
     return pl.pallas_call(
-        _masked_gram_kernel,
-        grid=(d // w,),
+        functools.partial(_masked_gram_kernel, d=_ragged(d, w)),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
             pl.BlockSpec((1, n), lambda i: (0, 0)),

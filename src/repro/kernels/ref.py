@@ -1,6 +1,7 @@
 """Pure-jnp oracles for the Pallas kernels (allclose targets in tests)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -36,9 +37,7 @@ def masked_impute_ref(g, mask, wn):
     delivered mean is not robust, so a mean-imputed ghost row lands
     inside the trim window under attack; they use the arrived-window
     statistics below instead."""
-    xf = g.astype(jnp.float32)
-    mean = jnp.sum(xf * wn.astype(jnp.float32)[:, None],
-                   axis=0).astype(g.dtype)
+    mean = weighted_row_sum(g, wn).astype(g.dtype)
     return jnp.where(mask.astype(bool)[:, None], g, mean[None])
 
 
@@ -122,6 +121,37 @@ def arrived_mean_closest_ref(g, mask, stat: str, f: int):
     keep = ranks < k
     out = jnp.sum(jnp.where(keep, xf, 0.0), axis=0) / k.astype(jnp.float32)
     return jnp.where(cnt > 0, out, 0.0)
+
+
+def weighted_row_sum(x, w):
+    """sum_i w_i x_i over the leading (agent) axis, in fp32, as ONE
+    contraction.  THE one copy of every weighted row sum whose bits a
+    parity test compares across paths (the imputed mean, the sparse
+    law's numerator): written as a multiply and a reduce, XLA's CPU
+    backend contracts the pair into FMAs in some fusion contexts and not
+    in others, which moves results by an ulp between two calls of the
+    same law; a dot is emitted the same way wherever it appears.
+    ``HIGHEST`` keeps the TPU from running the fp32 contraction in bf16."""
+    return jnp.tensordot(w.astype(jnp.float32), x.astype(jnp.float32),
+                         axes=1, precision=jax.lax.Precision.HIGHEST)
+
+
+def sparse_mean_law(xf, w):
+    """xf: (n, t) fp32, w: (n,) fp32 row weights (0 on dead rows) -> (t,)
+    ``agg_c = sum_i w_i x_ic / sum_i w_i`` over the rows that SENT
+    coordinate c (x_ic != 0), explicit 0 where nobody did (never an
+    eps-scaled garbage row).  The where-gate keeps 0 * non-finite == 0
+    exactly, so dead-row garbage cannot leak through a zero weight.
+    THE one copy of the law: the sparse kernels' tile body
+    (repro.kernels.wsum) and the engine's jnp path both call it."""
+    sent = xf != 0.0
+    xs = jnp.where(sent & (w[:, None] > 0.0), xf, 0.0)
+    num = jax.lax.dot_general(
+        w[None], xs, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[0]
+    den = jnp.sum(jnp.where(sent, w[:, None], 0.0), axis=0)
+    return jnp.where(den > 0.0, num / jnp.where(den > 0.0, den, 1.0), 0.0)
 
 
 def masked_sign_vote_ref(g, mask):

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.coord_stats import _sort_network
+from repro.kernels.tiling import block_d, resolve_interpret
 
 
 def _rank(values, ascending: bool = True):
@@ -58,6 +59,16 @@ def _rank(values, ascending: bool = True):
     before = (vj < vi) if ascending else (vj > vi)
     before = before | ((vj == vi) & (j < i))
     return jnp.sum(before.astype(jnp.int32), axis=1)     # (n,)
+
+
+def _first_true(b, axis: int):
+    """Keep only the first True along ``axis`` (lowest index) — the
+    first-index tie-break, as an iota comparison over the static axis
+    (Mosaic has no cumsum)."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, b.shape, axis)
+    first = jnp.min(jnp.where(b, idx, b.shape[axis]), axis=axis,
+                    keepdims=True)
+    return idx == first
 
 
 def _eye_and_diag(gr):
@@ -103,8 +114,9 @@ def _cge_select_kernel(gram_ref, out_ref, *, n_keep):
 
 
 @functools.partial(jax.jit, static_argnames=("f", "interpret"))
-def krum_select(gr, f: int, *, interpret: bool = True):
+def krum_select(gr, f: int, *, interpret: bool | None = None):
     """gr: (n, n) Gram -> (n,) one-hot fp32 Krum selection weights."""
+    interpret = resolve_interpret(interpret)
     n = gr.shape[0]
     return pl.pallas_call(
         functools.partial(_krum_select_kernel, f=f),
@@ -117,10 +129,11 @@ def krum_select(gr, f: int, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("n_keep", "interpret"))
-def cge_select(gr, n_keep: int, *, interpret: bool = True):
+def cge_select(gr, n_keep: int, *, interpret: bool | None = None):
     """gr: (n, n) Gram -> (n,) {0,1} fp32 keep-mask of the n_keep
     smallest-norm rows (unnormalized: the caller divides after the sum,
     exactly like the dense reference)."""
+    interpret = resolve_interpret(interpret)
     n = gr.shape[0]
     return pl.pallas_call(
         functools.partial(_cge_select_kernel, n_keep=n_keep),
@@ -190,13 +203,14 @@ def _iterative_order_kernel(gram_ref, out_ref, *, f, k_total):
         tied = (key == jnp.min(key)) & cand
         sec_eff = jnp.where(tied, sec, big)
         pool = tied & (sec_eff == jnp.min(sec_eff))
-        pick = pool & (jnp.cumsum(pool.astype(jnp.int32)) == 1)
+        pick = _first_true(pool, axis=0)
         order = jnp.where(pick, it, order)
         cand = cand & ~pick
     out_ref[...] = order[None]
 
 
 def _order_call(kernel, gr, *, interpret):
+    interpret = resolve_interpret(interpret)
     n = gr.shape[0]
     return pl.pallas_call(
         kernel,
@@ -209,7 +223,7 @@ def _order_call(kernel, gr, *, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("f", "m", "interpret"))
-def multi_krum_order(gr, f: int, m: int, *, interpret: bool = True):
+def multi_krum_order(gr, f: int, m: int, *, interpret: bool | None = None):
     """(n, n) Gram -> (n,) int32 order of the m smallest-score rows."""
     return _order_call(
         functools.partial(_multi_krum_order_kernel, f=f, m=m), gr,
@@ -217,7 +231,7 @@ def multi_krum_order(gr, f: int, m: int, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("f", "k_total", "interpret"))
-def iterative_order(gr, f: int, k_total: int, *, interpret: bool = True):
+def iterative_order(gr, f: int, k_total: int, *, interpret: bool | None = None):
     """(n, n) Gram -> (n,) int32 pick order of ``k_total`` shrinking-k
     iterative Krum selections (m-Krum / Bulyan stage 1)."""
     return _order_call(
@@ -232,32 +246,33 @@ def iterative_order(gr, f: int, k_total: int, *, interpret: bool = True):
 
 
 def _impute_tile(x, m, mean):
-    """The one imputation preamble: absent rows replaced by the
-    precomputed (T,) mean slice (repro.kernels.pairwise.imputed_mean —
-    bit-for-bit with the tree-level engine and kernels/masked.py)."""
-    return jnp.where(m[:, None] > 0.5, x, mean[None])
+    """The one imputation preamble: absent rows (``m``: (n, 1) column)
+    replaced by the precomputed (1, T) mean slice
+    (repro.kernels.pairwise.imputed_mean — bit-for-bit with the
+    tree-level engine and kernels/masked.py)."""
+    return jnp.where(m > 0.5, x, mean)
 
 
 def _bulyan_stage2(x, sel, *, theta, beta, exact):
-    """x: (n, T) fp32, sel: (n,) bool with exactly theta True.  Median of
+    """x: (n, T) fp32, sel: (n, 1) bool with exactly theta True.  Median of
     the selected rows via the sort network (+inf padding), then the mean
     of the beta selected values closest to it per coordinate — closeness
     ties by first index, summation in closeness order: exactly the dense
     reference's ``top_k`` + ``take_along_axis`` + ``mean``."""
     n = x.shape[0]
     big = jnp.float32(jnp.inf)
-    padded = jnp.where(sel[:, None], x, big)
+    padded = jnp.where(sel, x, big)
     s = _sort_network(padded)
     if exact:
         s = jax.lax.optimization_barrier(s)
     med = 0.5 * (s[(theta - 1) // 2] + s[theta // 2])
-    dist = jnp.where(sel[:, None], jnp.abs(x - med[None]), big)
-    avail = jnp.broadcast_to(sel[:, None], dist.shape)
+    dist = jnp.where(sel, jnp.abs(x - med[None]), big)
+    avail = jnp.broadcast_to(sel, dist.shape)
     rows = []
     for _ in range(beta):
         cur = jnp.where(avail, dist, big)
-        is_min = cur == jnp.min(cur, axis=0)[None]
-        first = is_min & (jnp.cumsum(is_min.astype(jnp.int32), axis=0) == 1)
+        is_min = cur == jnp.min(cur, axis=0, keepdims=True)
+        first = _first_true(is_min, axis=0)
         rows.append(jnp.sum(jnp.where(first, x, 0.0), axis=0))
         avail = avail & ~first
     stk = jnp.stack(rows, axis=0)
@@ -271,67 +286,65 @@ def _bulyan_stage2(x, sel, *, theta, beta, exact):
 
 def _bulyan_coord_kernel(g_ref, sel_ref, out_ref, *, theta, beta, exact):
     x = g_ref[...].astype(jnp.float32)
-    sel = sel_ref[...][0] > 0.5
+    sel = sel_ref[...] > 0.5                        # (n, 1) column
     out_ref[...] = _bulyan_stage2(x, sel, theta=theta, beta=beta,
                                   exact=exact)[None]
 
 
 def _masked_bulyan_coord_kernel(g_ref, mask_ref, mean_ref, sel_ref, out_ref,
                                 *, theta, beta, exact):
-    x = _impute_tile(g_ref[...], mask_ref[...][0], mean_ref[...][0])
-    sel = sel_ref[...][0] > 0.5
+    x = _impute_tile(g_ref[...], mask_ref[...], mean_ref[...])
+    sel = sel_ref[...] > 0.5                        # (n, 1) column
     out_ref[...] = _bulyan_stage2(x.astype(jnp.float32), sel, theta=theta,
                                   beta=beta, exact=exact)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("theta", "f", "interpret"))
-def bulyan_coord(g, sel, theta: int, f: int, *, interpret: bool = True):
+def bulyan_coord(g, sel, theta: int, f: int, *, interpret: bool | None = None):
     """g: (n, d), sel: (n,) {0,1} f32 (theta rows selected) -> (d,) fp32
-    Bulyan coordinate stage.  d must be a multiple of TILE_D."""
-    from repro.kernels.tiling import TILE_D, block_d
+    Bulyan coordinate stage."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     beta = max(theta - 2 * f, 1)
     w = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_bulyan_coord_kernel, theta=theta, beta=beta,
                           exact=interpret),
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, w), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(g, sel.astype(jnp.float32).reshape(1, n))
+    )(g, sel.astype(jnp.float32).reshape(n, 1))
     return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("theta", "f", "interpret"))
 def masked_bulyan_coord(g, mask, mean, sel, theta: int, f: int, *,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """Imputation-fused Bulyan coordinate stage: g stays native dtype,
     absent rows are imputed inside the tile from the precomputed (d,)
     ``mean`` (no (n, d) imputed copy)."""
-    from repro.kernels.tiling import TILE_D, block_d
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     beta = max(theta - 2 * f, 1)
     w = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_masked_bulyan_coord_kernel, theta=theta,
                           beta=beta, exact=interpret),
-        grid=(d // w,),
+        grid=(pl.cdiv(d, w),),
         in_specs=[
             pl.BlockSpec((n, w), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, w), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, w), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(g, mask.astype(jnp.float32).reshape(1, n), mean.reshape(1, d),
-      sel.astype(jnp.float32).reshape(1, n))
+    )(g, mask.astype(jnp.float32).reshape(n, 1), mean.reshape(1, d),
+      sel.astype(jnp.float32).reshape(n, 1))
     return out[0]

@@ -30,7 +30,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import TILE_D, block_d
+from repro.kernels import ref
+from repro.kernels.tiling import block_d, resolve_interpret
+
+
+def _row_spec(n: int):
+    return pl.BlockSpec((1, n), lambda i: (0, 0))
+
+
+def _col_spec(n: int):
+    return pl.BlockSpec((n, 1), lambda i: (0, 0))
+
+
+def _row_and_col(v):
+    """(n,) per-row operand -> its (1, n) row and (n, 1) column blocks.
+
+    Mosaic cannot reshape a boolean (n,) vector into an (n, 1) column
+    inside a kernel, so a kernel that needs a per-row value both as an
+    MXU row operand and as a per-row gate over the (n, T) tile gets both
+    layouts from the caller."""
+    v = v.astype(jnp.float32)
+    return v.reshape(1, -1), v.reshape(-1, 1)
 
 
 def _wsum_kernel(w_ref, g_ref, out_ref):
@@ -42,16 +62,16 @@ def _wsum_kernel(w_ref, g_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def weighted_sum(w, g, *, interpret: bool = True):
-    """w: (n,), g: (n, d) -> (d,) fp32.  d multiple of TILE_D."""
+def weighted_sum(w, g, *, interpret: bool | None = None):
+    """w: (n,), g: (n, d) -> (d,) fp32."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         _wsum_kernel,
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _row_spec(n),
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
@@ -61,7 +81,8 @@ def weighted_sum(w, g, *, interpret: bool = True):
     return out[0]
 
 
-def _masked_wsum_kernel(w_ref, g_ref, mask_ref, mean_ref, out_ref):
+def _masked_wsum_kernel(w_ref, wc_ref, m_ref, mc_ref, g_ref, mean_ref,
+                        out_ref):
     """Imputation-free w^T over the VIRTUALLY imputed stack: instead of
     materializing imputed rows even tile-locally, live selected rows are
     dotted raw (drop-unselected like the plain kernel) and ghost
@@ -69,52 +90,49 @@ def _masked_wsum_kernel(w_ref, g_ref, mask_ref, mean_ref, out_ref):
     algebraically the same sum, and exactly the selected imputed row's
     bits for a one-hot w (0-terms are literal zeros, the ghost term is
     where-gated so 0 * inf cannot leak)."""
-    w = w_ref[...][0].astype(jnp.float32)            # (n,)
-    x = g_ref[...]
-    live = mask_ref[...][0] > 0.5
-    mean = mean_ref[...][0].astype(jnp.float32)      # (T,)
+    w = w_ref[...]                                   # (1, n)
+    live = m_ref[...] > 0.5                          # (1, n)
+    gate = jnp.where(mc_ref[...] > 0.5, wc_ref[...], 0.0) > 0.0   # (n, 1)
+    mean = mean_ref[...].astype(jnp.float32)         # (1, T)
     w_live = jnp.where(live, w, 0.0)
-    xf = jnp.where((w_live > 0.0)[:, None], x.astype(jnp.float32), 0.0)
+    xf = jnp.where(gate, g_ref[...].astype(jnp.float32), 0.0)
     out = jax.lax.dot_general(
-        w_live[None], xf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[0]        # (T,)
+        w_live, xf, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)          # (1, T)
     ghost_w = jnp.sum(jnp.where(live, 0.0, w))
-    out_ref[...] = (out + jnp.where(ghost_w > 0.0, ghost_w * mean,
-                                    0.0))[None]
+    out_ref[...] = out + jnp.where(ghost_w > 0.0, ghost_w * mean, 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_weighted_sum(w, g, mask, mean, *, interpret: bool = True):
+def masked_weighted_sum(w, g, mask, mean, *, interpret: bool | None = None):
     """w: (n,) NON-NEGATIVE selection weights, g: (n, d) native dtype,
     mean: (d,) imputation value (repro.kernels.pairwise.imputed_mean) ->
     (d,) fp32 weighted sum over the MEAN-IMPUTED stack (imputation fused
-    per tile; mask/mean traced).  d multiple of TILE_D.
+    per tile; mask/mean traced).
 
     PRECONDITION: w >= 0 — the 0*inf guards gate rows on w > 0, so a
     negative weight would be silently dropped, not subtracted (the
     selection callers pass one-hot / {0,1} sets; signed weight vectors
     need the plain :func:`weighted_sum` on an imputed stack instead)."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         _masked_wsum_kernel,
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _row_spec(n), _col_spec(n), _row_spec(n), _col_spec(n),
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
             pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(w.reshape(1, n), g, mask.astype(jnp.float32).reshape(1, n),
-      mean.reshape(1, d))
+    )(*_row_and_col(w), *_row_and_col(mask), g, mean.reshape(1, d))
     return out[0]
 
 
-def _cclip_wsum_kernel(lam_ref, g_ref, v_ref, out_ref):
+def _cclip_wsum_kernel(lam_ref, lamc_ref, g_ref, v_ref, out_ref):
     """One centered-clip fixed-point step per tile:
 
         out = v + sum_i lam_i (x_i - v) = (1 - sum_i lam_i) v + lam^T X
@@ -124,83 +142,66 @@ def _cclip_wsum_kernel(lam_ref, g_ref, v_ref, out_ref):
     reduction — so the scalar stage stays outside; the model-sized
     multiply-accumulate is what fuses here).  Rows are gated on lam > 0,
     so a dead row carrying inf/NaN cannot leak through 0 * x."""
-    lam = lam_ref[...][0].astype(jnp.float32)         # (n,)
-    x = g_ref[...]
-    v = v_ref[...][0].astype(jnp.float32)             # (T,)
-    xf = jnp.where((lam > 0.0)[:, None], x.astype(jnp.float32), 0.0)
+    lam = lam_ref[...]                                # (1, n)
+    v = v_ref[...].astype(jnp.float32)                # (1, T)
+    xf = jnp.where(lamc_ref[...] > 0.0, g_ref[...].astype(jnp.float32),
+                   0.0)
     acc = jax.lax.dot_general(
-        lam[None], xf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[0]         # (T,)
-    out_ref[...] = ((1.0 - jnp.sum(lam)) * v + acc)[None]
+        lam, xf, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)           # (1, T)
+    out_ref[...] = (1.0 - jnp.sum(lam)) * v + acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def clipped_weighted_sum(lam, g, v, *, interpret: bool = True):
+def clipped_weighted_sum(lam, g, v, *, interpret: bool | None = None):
     """lam: (n,) NON-NEGATIVE clip-folded weights, g: (n, d) native dtype,
     v: (d,) fp32 current center -> (d,) fp32 updated center
     ``v + sum_i lam_i (g_i - v)`` — the application stage of one
     centered-clipping iteration (Karimireddy et al. momentum clipping),
-    fused per tile without materializing the (n, d) difference stack.
-    d multiple of TILE_D."""
+    fused per tile without materializing the (n, d) difference stack."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         _cclip_wsum_kernel,
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _row_spec(n), _col_spec(n),
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
             pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(lam.astype(jnp.float32).reshape(1, n), g,
-      v.astype(jnp.float32).reshape(1, d))
+    )(*_row_and_col(lam), g, v.astype(jnp.float32).reshape(1, d))
     return out[0]
 
 
-def _sparse_mean_body(xf, cw):
-    """Per-coordinate weighted mean over the rows that SENT the
-    coordinate: cw is the per-coordinate weight ((coord != 0) * row
-    weight), the where-gate keeps an unsent inf/NaN coordinate from
-    leaking through 0 * x, and a coordinate nobody sent yields an
-    explicit 0 update (zero-total guard) — identical arithmetic to
-    repro.core.aggregators._sparse_mean_law, the gather oracle."""
-    num = jnp.sum(jnp.where(cw > 0.0, xf, 0.0) * cw, axis=0)
-    den = jnp.sum(cw, axis=0)
-    return jnp.where(den > 0.0, num / jnp.where(den > 0.0, den, 1.0), 0.0)
-
-
 def _sparse_wmean_kernel(g_ref, mask_ref, w_ref, out_ref):
-    x = g_ref[...]
     live = mask_ref[...][0] > 0.5
     w = jnp.where(live, w_ref[...][0].astype(jnp.float32), 0.0)   # (n,)
-    xf = x.astype(jnp.float32)
-    cw = (xf != 0.0).astype(jnp.float32) * w[:, None]
-    out_ref[...] = _sparse_mean_body(xf, cw)[None]
+    out_ref[...] = ref.sparse_mean_law(g_ref[...].astype(jnp.float32),
+                                       w)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sparse_masked_weighted_mean(g, mask, w, *, interpret: bool = True):
+def sparse_masked_weighted_mean(g, mask, w, *, interpret: bool | None = None):
     """g: (n, d) native dtype, mask: (n,) {0,1}, w: (n,) row weights
     (dataset sizes — any positive scaling; the law is scale-invariant) ->
     (d,) fp32 sparse/dropout-aware mean: each coordinate is averaged over
     the LIVE rows that actually sent it (coord != 0), weighted by
     ``(coord_sent) * w``.  Absent rows never vote — there is no
     imputation (a dropped-out coordinate is information-free, unlike a
-    straggler's stale full row).  d multiple of TILE_D."""
+    straggler's stale full row)."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         _sparse_wmean_kernel,
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _row_spec(n), _row_spec(n),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
@@ -214,31 +215,27 @@ def _scaled_sparse_wmean_kernel(g_ref, sc_ref, mask_ref, w_ref, out_ref):
     # quantized codes: code == 0 iff the dequantized coordinate == 0
     # (scales are strictly positive), so the sent-pattern survives
     # quantization and the in-tile dequant feeds the same law
-    x = g_ref[...]
     sc = sc_ref[...][0]
     live = mask_ref[...][0] > 0.5
     w = jnp.where(live, w_ref[...][0].astype(jnp.float32), 0.0)
-    xf = x.astype(jnp.float32) * sc[:, None]
-    cw = (xf != 0.0).astype(jnp.float32) * w[:, None]
-    out_ref[...] = _sparse_mean_body(xf, cw)[None]
+    xf = g_ref[...].astype(jnp.float32) * sc[:, None]
+    out_ref[...] = ref.sparse_mean_law(xf, w)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def scaled_sparse_masked_weighted_mean(g, scale, mask, w, *,
-                                       interpret: bool = True):
+                                       interpret: bool | None = None):
     """Sparse mean over a quantized arena: in-tile dequant (per-row fp32
     scale sidecar), then :func:`sparse_masked_weighted_mean`'s law."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         _scaled_sparse_wmean_kernel,
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _row_spec(n), _row_spec(n), _row_spec(n),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
@@ -282,9 +279,9 @@ def _accumulate_rows(rows, *, chain, div, true_div, exact):
 
 def _ordered_apply_kernel(ord_ref, g_ref, out_ref, *, k, chain, div,
                           true_div, exact):
-    order = ord_ref[...][0]                        # (n,) int32
+    order = ord_ref[...]                           # (n, 1) int32 column
     x = g_ref[...].astype(jnp.float32)             # (n, T)
-    rows = [jnp.sum(jnp.where((order == r)[:, None], x, 0.0), axis=0)
+    rows = [jnp.sum(jnp.where(order == r, x, 0.0), axis=0)
             for r in range(k)]
     out_ref[...] = _accumulate_rows(rows, chain=chain, div=div,
                                     true_div=true_div, exact=exact)[None]
@@ -298,14 +295,14 @@ def _masked_ordered_apply_kernel(ord_ref, g_ref, mask_ref, mean_ref,
     extract + literal-zero mean term), a ghost rank contributes exactly
     the precomputed mean's bits — so no imputed tile is ever built and
     parity with the impute-then-extract arithmetic is bitwise."""
-    order = ord_ref[...][0]
+    order = ord_ref[...]                           # (n, 1) int32 column
     x = g_ref[...].astype(jnp.float32)
-    live = mask_ref[...][0] > 0.5
+    live = mask_ref[...] > 0.5                     # (n, 1)
     mean = mean_ref[...][0].astype(jnp.float32)
     rows = []
     for r in range(k):
         sel = order == r
-        row = jnp.sum(jnp.where((sel & live)[:, None], x, 0.0), axis=0)
+        row = jnp.sum(jnp.where(sel & live, x, 0.0), axis=0)
         ghost = jnp.sum((sel & ~live).astype(jnp.float32)) > 0.0
         rows.append(row + jnp.where(ghost, mean, 0.0))
     out_ref[...] = _accumulate_rows(rows, chain=chain, div=div,
@@ -317,26 +314,26 @@ def _masked_ordered_apply_kernel(ord_ref, g_ref, mask_ref, mean_ref,
                                     "interpret"))
 def ordered_apply(order, g, k: int, *, chain: bool = False,
                   div: float | None = None, true_div: bool = True,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """order: (n,) int32 pick order (sentinel >= k ignored), g: (n, d) ->
     (d,) fp32: the k picked rows summed in pick order, divided by ``div``
     (None = no division; ``true_div`` picks the reference's division
-    compilation — see _ordered_accumulate).  d multiple of TILE_D."""
+    compilation — see _accumulate_rows)."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_ordered_apply_kernel, k=k, chain=chain, div=div,
                           true_div=true_div, exact=interpret),
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _col_spec(n),
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(order.reshape(1, n).astype(jnp.int32), g)
+    )(order.reshape(n, 1).astype(jnp.int32), g)
     return out[0]
 
 
@@ -345,26 +342,27 @@ def ordered_apply(order, g, k: int, *, chain: bool = False,
                                     "interpret"))
 def masked_ordered_apply(order, g, mask, mean, k: int, *,
                          chain: bool = False, div: float | None = None,
-                         true_div: bool = True, interpret: bool = True):
+                         true_div: bool = True,
+                         interpret: bool | None = None):
     """Imputation-fused :func:`ordered_apply`: g stays native dtype and
     absent rows are imputed inside the tile from the precomputed (d,)
     ``mean`` (mask/mean are traced operands)."""
+    interpret = resolve_interpret(interpret)
     n, d = g.shape
-    assert d % TILE_D == 0, d
     w_blk = block_d(d, interpret)
     out = pl.pallas_call(
         functools.partial(_masked_ordered_apply_kernel, k=k, chain=chain,
                           div=div, true_div=true_div, exact=interpret),
-        grid=(d // w_blk,),
+        grid=(pl.cdiv(d, w_blk),),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _col_spec(n),
             pl.BlockSpec((n, w_blk), lambda i: (0, i)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            _col_spec(n),
             pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, w_blk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(order.reshape(1, n).astype(jnp.int32), g,
-      mask.astype(jnp.float32).reshape(1, n), mean.reshape(1, d))
+    )(order.reshape(n, 1).astype(jnp.int32), g,
+      mask.astype(jnp.float32).reshape(n, 1), mean.reshape(1, d))
     return out[0]

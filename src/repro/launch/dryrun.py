@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# the dry-run lowers against 512 fake HOST devices: on a TPU host JAX would
+# otherwise take the chip and then fail to build the 512-device mesh
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run entry point.
 

@@ -225,10 +225,7 @@ def analyze(lowered, compiled, wall: dict):
     under-reporting scanned-layer programs by ~num_layers.  The raw XLA
     numbers are kept under *_xla for reference."""
     from repro.launch.hlo_cost import analyze_hlo_text
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    cost = dict(cost or {})
+    cost = dict(compiled.cost_analysis() or {})
     mem = {}
     try:
         ma = compiled.memory_analysis()

@@ -56,6 +56,9 @@ def main():
                          "zero-selection eviction window")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
 

@@ -1,12 +1,10 @@
-"""Training launcher.
+"""Training launcher (single device: one TPU chip, or the CPU).
 
-CPU smoke scale:
   PYTHONPATH=src python -m repro.launch.train --arch paper-100m --steps 200 \
       --filter trimmed_mean --attack sign_flip --f 3
 
-On a real TPU slice the same entry point runs under the production mesh
-(--mesh pod) with the sharded train step — the dry-run proves those programs
-compile for 256/512 chips.
+Compiled programs are cached under ``JAX_COMPILATION_CACHE_DIR`` when it is
+set, else under ``<checkout>/.jax_cache`` (repro.launch.compile_cache).
 """
 from __future__ import annotations
 
@@ -61,6 +59,9 @@ def main():
                     "Perfetto JSON of the run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.core.aggregators import elastic, make_spec

@@ -50,7 +50,7 @@ def provenance() -> dict:
     except Exception:
         pass
     try:
-        from repro.kernels.dispatch import default_interpret
+        from repro.kernels.tiling import default_interpret
         rec["interpret"] = bool(default_interpret())
     except Exception:
         pass

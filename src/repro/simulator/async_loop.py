@@ -68,6 +68,7 @@ from repro.core.redundancy.coding import (coding_groups,
                                           flat_draco_aggregate,
                                           tree_draco_aggregate)
 from repro.data import label_flip
+from repro.distributed.context import layer_remat
 from repro.models import init_params, loss_fn
 from repro.optim import apply_updates
 from repro.simulator.events import AsyncTrace, simulate_arrivals
@@ -200,8 +201,12 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
         if adaptive is not None:
             atk_state, agg_state = agg_state["atk"], agg_state["agg"]
         # (2) fresh gradients at the current version for dispatching agents
-        losses, grads = jax.vmap(
-            jax.value_and_grad(agent_loss), in_axes=(None, 0))(params, batch)
+        # (bz.remat: per-layer activation checkpointing, as in the
+        # synchronous step)
+        with layer_remat(bz.remat):
+            losses, grads = jax.vmap(
+                jax.value_and_grad(agent_loss), in_axes=(None, 0))(
+                    params, batch)
         if bz.momentum_alpha > 0.0:
             new_m, sent_now = worker_momentum(momentum, grads,
                                               bz.momentum_alpha)
@@ -445,6 +450,9 @@ def async_train_loop(cfg, bz, optimizer, dataset, steps: int,
     k_init, k_run = jax.random.split(key)
     if params is None:
         params = init_params(cfg, k_init)
+    elif jit and jax.default_backend() != "cpu":
+        # the steps donate params (below): keep the caller's arrays alive
+        params = jax.tree.map(jnp.copy, params)
     opt_state = optimizer.init(params)
     momentum = None
     if bz.momentum_alpha > 0.0:
@@ -468,16 +476,20 @@ def async_train_loop(cfg, bz, optimizer, dataset, steps: int,
     step_fn = (None if stateful or adaptive
                else make_train_step(cfg, bz, optimizer,
                                     telemetry=telemetry))
-    # donate the in-flight gradient buffer (the step returns its updated
-    # twin): on accelerator backends the buffer-sized HBM block is reused
-    # in place — the flat pipeline's "donated arena"; CPU ignores
-    # donation, so skip it there to keep logs clean
-    donate = () if jax.default_backend() == "cpu" else (3,)
+    # donate params, optimizer state and momentum (and, on the async path,
+    # the in-flight gradient buffer): every step returns their updated
+    # twins, so on accelerator backends those HBM blocks are reused in
+    # place instead of held twice across the step.  CPU ignores donation,
+    # so skip it there to keep logs clean
+    on_cpu = jax.default_backend() == "cpu"
+    donate_sync = () if on_cpu else (0, 1, 2)
+    donate = () if on_cpu else (0, 1, 2, 3)
     async_fn = make_async_step(cfg, bz, optimizer,
                                fallback_r=sim.coded_fallback_r,
                                telemetry=telemetry)
     if jit:
-        step_fn = jax.jit(step_fn) if step_fn is not None else None
+        step_fn = (jax.jit(step_fn, donate_argnums=donate_sync)
+                   if step_fn is not None else None)
         async_fn = jax.jit(async_fn, donate_argnums=donate)
 
     # elastic membership: one step function per roster BUCKET (built
@@ -518,9 +530,11 @@ def async_train_loop(cfg, bz, optimizer, dataset, steps: int,
     # refreshes deferred across update-less steps: params are unchanged
     # there, so the gradient is computed at the correct parameter version
     # (the data batch is a fresh sample from a later step index — iid-
-    # equivalent, though not the literal batch of the dispatch instant)
-    buffer = jax.tree.map(
-        lambda p: jnp.zeros((n,) + p.shape, jnp.float32), params)
+    # equivalent, though not the literal batch of the dispatch instant).
+    # Only the general path reads the buffer: a run whose every step is
+    # pure never allocates its (n, P) fp32
+    buffer = (None if pure.all() else jax.tree.map(
+        lambda p: jnp.zeros((n,) + p.shape, jnp.float32), params))
     pending_refresh = np.zeros(n, bool)
 
     history = []

@@ -428,7 +428,7 @@ def _collect_shapes(jaxpr, banned=("select_n", "broadcast_in_dim")):
     (recursion stops at pallas_call: the tile-level where IS the fusion —
     what must never exist is a full-size imputed copy feeding the
     kernel)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     hits = []
 
     def walk(jx):
