@@ -1,6 +1,6 @@
 """Backward-compat shim — the trace-count bookkeeping moved to
 :mod:`repro.obs.counters` (PR 6), which adds the public
-``snapshot()``/``reset()``/gauge API the flight recorder builds its
+``snapshot()``/``reset()`` API the flight recorder builds its
 recompile ledger on.  ``TRACE_COUNTS`` here IS the same Counter object as
 ``repro.obs.counters.COUNTERS``, so existing snapshot-diff tests keep
 working unchanged.  New code should import from ``repro.obs.counters``.

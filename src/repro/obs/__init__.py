@@ -1,13 +1,20 @@
 """Flight-recorder observability substrate (PR 6).
 
-Three layers, consumed by the training loops, the async simulator and the
+Its layers, consumed by the training loops, the async simulator and the
 replicated serving engine:
 
 :mod:`repro.obs.counters`
-    Process-global compile counters (the promoted ``core/tracecount``) and
-    host-side gauges, with a public ``snapshot()``/``reset()`` API — the
-    substrate every compile-regression test and the recorder's recompile
-    ledger read from.
+    Process-global compile counters (the promoted ``core/tracecount``),
+    with a public ``snapshot()``/``reset()`` API — the substrate every
+    compile-regression test and the recorder's recompile ledger read from.
+
+:mod:`repro.obs.spans`
+    Host spans on the JAX profiler's clock (``TraceAnnotation``): the
+    serving scheduler's step, decode, logits pull, commit, vote,
+    admission and queue wait, and the training loop's step, batch,
+    dispatch, log and checkpoint.  They share the device trace's clock,
+    so XProf or Perfetto shows what the host did in each device gap;
+    the chip benchmark's serving metrics read them by name.
 
 :mod:`repro.obs.telemetry`
     Per-step aggregation telemetry: fixed-shape per-agent selection

@@ -1,4 +1,4 @@
-"""Process-global counters and gauges — the compile-accounting substrate.
+"""Process-global counters — the compile-accounting substrate.
 
 Promoted from ``repro.core.tracecount`` (which remains as a
 backward-compat shim): ``count_trace(site)`` is called from INSIDE
@@ -12,9 +12,8 @@ step to emit its recompile ledger.
 
 Counters are monotonic; consumers snapshot before/after rather than
 resetting blindly (tests sharing the process must not clobber each
-other).  Gauges are last-write-wins host-side values (live roster size,
-arrived count, staleness) the loops publish for scrapers that want the
-current state without parsing a trace.
+other).  What the host is doing over time is not counted here: the
+loops open profiler spans for that (:mod:`repro.obs.spans`).
 """
 from __future__ import annotations
 
@@ -23,7 +22,6 @@ from collections import Counter
 # the ONE counter store — repro.core.tracecount aliases this same object,
 # so legacy TRACE_COUNTS reads see every inc() and vice versa
 COUNTERS: Counter = Counter()
-GAUGES: dict = {}
 
 # legacy alias (same object, not a copy)
 TRACE_COUNTS: Counter = COUNTERS
@@ -43,21 +41,12 @@ def trace_count(site: str) -> int:
     return COUNTERS[site]
 
 
-def set_gauge(name: str, value) -> None:
-    """Publish a last-write-wins host-side gauge value."""
-    GAUGES[name] = value
-
-
-def gauge(name: str, default=None):
-    return GAUGES.get(name, default)
-
-
 def snapshot() -> dict:
-    """Point-in-time copy: ``{"counters": {...}, "gauges": {...}}``.
+    """Point-in-time copy: ``{"counters": {...}}``.
 
     Plain dicts (detached from the live stores), so two snapshots diff
     safely across any amount of intervening work."""
-    return {"counters": dict(COUNTERS), "gauges": dict(GAUGES)}
+    return {"counters": dict(COUNTERS)}
 
 
 def counter_delta(before: dict, after: dict | None = None) -> dict:
@@ -75,14 +64,12 @@ def counter_delta(before: dict, after: dict | None = None) -> dict:
 
 
 def reset(name: str | None = None) -> None:
-    """Clear counters and gauges (one name, or everything).  Prefer
-    snapshot-diffing in tests — reset is for interactive sessions."""
+    """Clear counters (one name, or everything).  Prefer snapshot-
+    diffing in tests — reset is for interactive sessions."""
     if name is None:
         COUNTERS.clear()
-        GAUGES.clear()
     else:
         COUNTERS.pop(name, None)
-        GAUGES.pop(name, None)
 
 
 def reset_traces(site: str | None = None) -> None:
@@ -94,7 +81,6 @@ def reset_traces(site: str | None = None) -> None:
 
 
 __all__ = [
-    "COUNTERS", "GAUGES", "TRACE_COUNTS", "inc", "count_trace",
-    "trace_count", "set_gauge", "gauge", "snapshot", "counter_delta",
-    "reset", "reset_traces",
+    "COUNTERS", "TRACE_COUNTS", "inc", "count_trace", "trace_count",
+    "snapshot", "counter_delta", "reset", "reset_traces",
 ]

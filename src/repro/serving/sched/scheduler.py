@@ -66,6 +66,10 @@ import numpy as np
 
 from repro.models import decode_step, init_cache, prefill
 from repro.obs.counters import count_trace
+from repro.obs.spans import (SCHED_ADMIT, SCHED_COMMIT, SCHED_DECODE,
+                             SCHED_PREFILL, SCHED_QUEUED, SCHED_RESIZE,
+                             SCHED_SPLICE, SCHED_STEP, SCHED_TO_HOST,
+                             SCHED_VOTE, close_span, open_span, span)
 from repro.serving.agreement import Agreement
 from repro.serving.sched.metrics import ServingMetrics
 from repro.serving.sched.queue import Request, RequestQueue
@@ -218,6 +222,7 @@ class ReplicatedScheduler:
                                    jit=self.jit, site="sched_agree")
 
         self.queue = queue if queue is not None else RequestQueue()
+        self._queued: dict = {}           # id(request) -> its open span
         self.metrics = ServingMetrics()
         self.clock = 0.0
         self.step_idx = 0
@@ -336,7 +341,10 @@ class ReplicatedScheduler:
                 f"{self.seq_capacity}")
         if req.max_new_tokens < 1:
             raise ValueError(f"request {req.rid}: empty decode budget")
-        return self.queue.submit(req)
+        if not self.queue.submit(req):
+            return False
+        self._queued[id(req)] = open_span(SCHED_QUEUED, rid=req.rid)
+        return True
 
     def submit_all(self, reqs) -> int:
         return sum(1 for r in reqs if self.submit(r))
@@ -350,20 +358,22 @@ class ReplicatedScheduler:
         target = next(b for b in self.buckets if b >= n_needed)
         if target == self.bucket:
             return
-        if target > self.bucket:
-            extra = target - self.bucket
-            self.slab = slab_grow(self.slab, self._axes, extra)
-            self.slots += [None] * extra
-            self.cur_token = np.concatenate(
-                [self.cur_token, np.zeros(extra, np.int32)])
-        else:                                     # repack actives, shrink
-            keep = self._active_ids()
-            free = [i for i in range(self.bucket) if self.slots[i] is None]
-            idx = (keep + free)[:target]
-            self.slab = slab_take(self.slab, self._axes, idx)
-            self.slots = [self.slots[i] for i in idx]
-            self.cur_token = self.cur_token[np.asarray(idx, int)]
-        self.bucket = target
+        with span(SCHED_RESIZE, **{"from": self.bucket, "to": target}):
+            if target > self.bucket:
+                extra = target - self.bucket
+                self.slab = slab_grow(self.slab, self._axes, extra)
+                self.slots += [None] * extra
+                self.cur_token = np.concatenate(
+                    [self.cur_token, np.zeros(extra, np.int32)])
+            else:                                 # repack actives, shrink
+                keep = self._active_ids()
+                free = [i for i in range(self.bucket)
+                        if self.slots[i] is None]
+                idx = (keep + free)[:target]
+                self.slab = slab_take(self.slab, self._axes, idx)
+                self.slots = [self.slots[i] for i in idx]
+                self.cur_token = self.cur_token[np.asarray(idx, int)]
+            self.bucket = target
 
     def _commit_tokens(self, logits, active: list[int], now: float,
                        phase: str):
@@ -372,56 +382,61 @@ class ReplicatedScheduler:
         Returns (per-slot token dict, latest commit time, telemetry row).
         ``logits`` is the post-fault-hook (r, B, V) stack; ``phase`` only
         labels the recorder event ("decode" | "prefill")."""
-        live = self._live()
-        d = self._step_delays(self.step_idx)
-        la = np.asarray(logits, np.float32) if logits.dtype != jnp.float32 \
-            else np.asarray(logits)
-        amax = la.argmax(axis=-1)                 # (r, B) fp32 argmax
-        n_live = int(live.sum())
-        q = self._f_eff(n_live) + 1
-        t_star, tok_star = self._commit_walk(amax, live, d, q)
-        limit = np.inf if self.deadline is None else float(self.deadline)
-        full_wait = float(d[live].max()) if n_live else 0.0
+        with span(SCHED_TO_HOST, shape=tuple(logits.shape)):
+            la = np.asarray(logits, np.float32)
+        with span(SCHED_COMMIT, n_slots=len(active)):
+            live = self._live()
+            d = self._step_delays(self.step_idx)
+            amax = la.argmax(axis=-1)             # (r, B) fp32 argmax
+            n_live = int(live.sum())
+            q = self._f_eff(n_live) + 1
+            t_star, tok_star = self._commit_walk(amax, live, d, q)
+            limit = (np.inf if self.deadline is None
+                     else float(self.deadline))
+            full_wait = float(d[live].max()) if n_live else 0.0
 
-        tokens: dict[int, int] = {}
-        times: dict[int, float] = {}
-        early: dict[int, bool] = {}
-        fallback = [b for b in active
-                    if not (self.early_commit and t_star[b] <= limit)]
-        vote_tok, vote_telem = None, None
-        if fallback:
-            out = self.agreement.vote(logits, live if self.policy is not None
-                                      else None)
+            tokens: dict[int, int] = {}
+            times: dict[int, float] = {}
+            early: dict[int, bool] = {}
+            fallback = [b for b in active
+                        if not (self.early_commit and t_star[b] <= limit)]
+            vote_tok, vote_telem = None, None
+            if fallback:
+                with span(SCHED_VOTE):
+                    out = self.agreement.vote(
+                        logits, live if self.policy is not None else None)
+                    if self.telemetry:
+                        vote_tok, vote_telem = out
+                    else:
+                        vote_tok = out
+                    vote_tok = np.asarray(vote_tok)
+            for b in active:
+                if b in fallback:
+                    tokens[b] = int(vote_tok[b])
+                    times[b] = now + full_wait
+                    early[b] = False
+                else:
+                    tokens[b] = int(tok_star[b])
+                    times[b] = now + float(t_star[b])
+                    early[b] = True
+
+            telem = None
             if self.telemetry:
-                vote_tok, vote_telem = out
-            else:
-                vote_tok = out
-            vote_tok = np.asarray(vote_tok)
-        for b in active:
-            if b in fallback:
-                tokens[b] = int(vote_tok[b])
-                times[b] = now + full_wait
-                early[b] = False
-            else:
-                tokens[b] = int(tok_star[b])
-                times[b] = now + float(t_star[b])
-                early[b] = True
-
-        telem = None
-        if self.telemetry:
-            if vote_telem is not None:
-                telem = {k: np.asarray(v) for k, v in vote_telem.items()}
-            else:
-                # all-early step: the vote never ran — replica shares are
-                # argmax agreement over the committed slots
-                agree_frac = np.zeros(self.r, np.float64)
-                if active:
-                    hits = np.stack([amax[:, b] == tokens[b]
-                                     for b in active], axis=1)
-                    agree_frac = np.where(live, hits.mean(axis=1), 0.0)
-                telem = {"sel_w": agree_frac.astype(np.float32),
-                         "mask": live, "contrib_w": live.astype(np.float32)}
-        return tokens, times, early, telem, live
+                if vote_telem is not None:
+                    telem = {k: np.asarray(v)
+                             for k, v in vote_telem.items()}
+                else:
+                    # all-early step: the vote never ran — replica shares
+                    # are argmax agreement over the committed slots
+                    agree_frac = np.zeros(self.r, np.float64)
+                    if active:
+                        hits = np.stack([amax[:, b] == tokens[b]
+                                         for b in active], axis=1)
+                        agree_frac = np.where(live, hits.mean(axis=1), 0.0)
+                    telem = {"sel_w": agree_frac.astype(np.float32),
+                             "mask": live,
+                             "contrib_w": live.astype(np.float32)}
+            return tokens, times, early, telem, live
 
     # -- the step --------------------------------------------------------
     def step(self) -> bool:
@@ -429,8 +444,13 @@ class ReplicatedScheduler:
 
         Returns False when there was nothing to do AND nothing is queued
         (the drain condition)."""
-        now = self.clock
         active = self._active_ids()
+        with span(SCHED_STEP, step=self.step_idx, active=len(active),
+                  bucket=self.bucket, queued=len(self.queue)):
+            return self._step(active)
+
+    def _step(self, active: list[int]) -> bool:
+        now = self.clock
         if not active and len(self.queue) == 0:
             return False
         if not active:
@@ -442,11 +462,13 @@ class ReplicatedScheduler:
         t_end = now
         telem, live = None, self._live()
         if active:
-            dec = self._decode_fn(self.bucket)
-            tok = jnp.asarray(self.cur_token[:, None])
-            logits, self.slab = dec(self.params_stack, tok, self.slab)
-            if self.fault_hook is not None:
-                logits = self.fault_hook(self.step_idx, logits)
+            with span(SCHED_DECODE, bucket=self.bucket):
+                dec = self._decode_fn(self.bucket)
+                tok = jnp.asarray(self.cur_token[:, None])
+                logits, self.slab = dec(self.params_stack, tok, self.slab)
+                if self.fault_hook is not None:
+                    logits = self.fault_hook(self.step_idx, logits)
+                jax.block_until_ready(logits)
             tokens, times, early, telem, live = self._commit_tokens(
                 logits, active, now, "decode")
             for b in active:
@@ -488,26 +510,35 @@ class ReplicatedScheduler:
 
     def _admit(self, req: Request, slot: int, now: float) -> float:
         """Prefill ``req`` into ``slot`` and commit its first token."""
-        pf = self._prefill_fn(req.prompt_len)
-        tokens = jnp.asarray(np.asarray(req.tokens, np.int32)[None, :])
-        logits, rows = pf(self.params_stack, tokens)  # (r, 1, V), cache
-        if self.fault_hook is not None:
-            logits = self.fault_hook(self.step_idx, logits)
-        tokens_d, times_d, early_d, _, _ = self._commit_tokens(
-            logits, [0], now, "prefill")
-        tok0 = tokens_d[0]
-        self.metrics.admit(req, now)
-        req.out.append(tok0)
-        self.metrics.commit(req, times_d[0], times_d[0] - now, early_d[0])
-        self.slots[slot] = req
-        self.cur_token[slot] = tok0
-        self.slab = slab_write(self.slab, self._axes,
-                               vectorize_cache_pos(rows, 1), [slot])
-        if req.done:                               # budget of exactly 1
-            self.metrics.finish(req, times_d[0])
-            self.slots[slot] = None
-            self.cur_token[slot] = 0
-        return times_d[0]
+        queued = self._queued.pop(id(req), None)
+        if queued is not None:
+            close_span(queued)
+        with span(SCHED_ADMIT, rid=req.rid, prompt_len=req.prompt_len):
+            with span(SCHED_PREFILL):
+                pf = self._prefill_fn(req.prompt_len)
+                tokens = jnp.asarray(
+                    np.asarray(req.tokens, np.int32)[None, :])
+                logits, rows = pf(self.params_stack, tokens)  # (r, 1, V)
+                if self.fault_hook is not None:
+                    logits = self.fault_hook(self.step_idx, logits)
+                jax.block_until_ready(logits)
+            tokens_d, times_d, early_d, _, _ = self._commit_tokens(
+                logits, [0], now, "prefill")
+            tok0 = tokens_d[0]
+            self.metrics.admit(req, now)
+            req.out.append(tok0)
+            self.metrics.commit(req, times_d[0], times_d[0] - now,
+                                early_d[0])
+            self.slots[slot] = req
+            self.cur_token[slot] = tok0
+            with span(SCHED_SPLICE):
+                self.slab = slab_write(self.slab, self._axes,
+                                       vectorize_cache_pos(rows, 1), [slot])
+            if req.done:                           # budget of exactly 1
+                self.metrics.finish(req, times_d[0])
+                self.slots[slot] = None
+                self.cur_token[slot] = 0
+            return times_d[0]
 
     # -- driving ---------------------------------------------------------
     def run(self, max_steps: int | None = None) -> ServingMetrics:

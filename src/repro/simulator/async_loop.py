@@ -61,6 +61,8 @@ from repro.core.aggregators import tree_stack_ravel, tree_where_agents
 from repro.core.flat import (FlatPlan, QUANT_DTYPES, fake_quantize,
                              quantize_rows)
 from repro.obs.counters import count_trace
+from repro.obs.spans import (TRAIN_BATCH, TRAIN_CHECKPOINT, TRAIN_DISPATCH,
+                             TRAIN_LOG, TRAIN_STEP, span, step_span)
 from repro.core.attacks import (get_attack, is_adaptive_attack,
                                 make_adaptive_attack, make_byzantine_mask)
 from repro.core.momentum import init_momentum, worker_momentum
@@ -203,129 +205,146 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
         # (2) fresh gradients at the current version for dispatching agents
         # (bz.remat: per-layer activation checkpointing, as in the
         # synchronous step)
-        with layer_remat(bz.remat):
-            losses, grads = jax.vmap(
-                jax.value_and_grad(agent_loss), in_axes=(None, 0))(
-                    params, batch)
-        if bz.momentum_alpha > 0.0:
-            new_m, sent_now = worker_momentum(momentum, grads,
-                                              bz.momentum_alpha)
-            momentum = tree_where_agents(refresh, new_m, momentum)
-            grads = sent_now
-        buffer = tree_where_agents(refresh, grads, buffer)
+        with jax.named_scope("grads"):
+            with layer_remat(bz.remat):
+                losses, grads = jax.vmap(
+                    jax.value_and_grad(agent_loss), in_axes=(None, 0))(
+                        params, batch)
+            if bz.momentum_alpha > 0.0:
+                new_m, sent_now = worker_momentum(momentum, grads,
+                                                  bz.momentum_alpha)
+                momentum = tree_where_agents(refresh, new_m, momentum)
+                grads = sent_now
+        with jax.named_scope("buffer"):
+            buffer = tree_where_agents(refresh, grads, buffer)
 
         # (3) Byzantine corruption happens at delivery time, on whatever is
         # in flight — stale honest gradients stay honest, Byzantine rows are
         # arbitrary every round (matches the synchronous injection point)
         sent = buffer
         if attack_fn is not None:
-            sent = tree_attack(attack_fn, key, sent, byz_mask)
+            with jax.named_scope("attack"):
+                sent = tree_attack(attack_fn, key, sent, byz_mask)
         elif adaptive is not None:
             # defense-aware attacks operate on the raveled (n, P) arena —
             # min-max needs whole-row geometry, not per-leaf slices.  The
             # omniscient adversary also reads the defense's carried center
             # (state-aware threat model).
-            aplan = FlatPlan.for_tree(sent)
-            arows = aplan.ravel(sent, jnp.float32)
-            dvec = None
-            if stateful and "server_grad" in agg_state:
-                dvec = tree_stack_ravel(jax.tree.map(
-                    lambda l: l.astype(jnp.float32)[None],
-                    agg_state["server_grad"]))[0]
-            arows, atk_state = adaptive(key, arows, byz_mask, atk_state,
-                                        dvec)
-            sent = aplan.unravel_stack(arows)
-        if bz.agg_dtype and not quant:
-            sent = jax.tree.map(
-                lambda l: l.astype(jnp.dtype(bz.agg_dtype)), sent)
-
+            with jax.named_scope("attack"):
+                aplan = FlatPlan.for_tree(sent)
+                arows = aplan.ravel(sent, jnp.float32)
+                dvec = None
+                if stateful and "server_grad" in agg_state:
+                    dvec = tree_stack_ravel(jax.tree.map(
+                        lambda l: l.astype(jnp.float32)[None],
+                        agg_state["server_grad"]))[0]
+                arows, atk_state = adaptive(key, arows, byz_mask, atk_state,
+                                            dvec)
+                sent = aplan.unravel_stack(arows)
         mask = contrib_w > 0.0
-        plan = FlatPlan.for_tree(sent)
-        codes = qs = arena = None
-        if quant:
-            # quantize the wire: codes + per-row fp32 scale.  Codes feed
-            # the scaled kernels only on the plain flat path; the coded
-            # vote (Gram-based, no scaled kernels) and the tree fallbacks
-            # see the fake-quantized fp32 stack instead — identical
-            # compressed-exchange semantics on every path.
-            arena = plan.ravel(sent, jnp.float32)
-            qdt = jnp.dtype(bz.agg_dtype)
-            if use_flat and bz.draco_r == 0:
-                if fallback_r > 0:
-                    arena = fake_quantize(arena, qdt)
+        with jax.named_scope("arena"):
+            if bz.agg_dtype and not quant:
+                sent = jax.tree.map(
+                    lambda l: l.astype(jnp.dtype(bz.agg_dtype)), sent)
+            plan = FlatPlan.for_tree(sent)
+            codes = qs = arena = None
+            if quant:
+                # quantize the wire: codes + per-row fp32 scale.  Codes
+                # feed the scaled kernels only on the plain flat path; the
+                # coded vote (Gram-based, no scaled kernels) and the tree
+                # fallbacks see the fake-quantized fp32 stack instead —
+                # identical compressed-exchange semantics on every path.
+                arena = plan.ravel(sent, jnp.float32)
+                qdt = jnp.dtype(bz.agg_dtype)
+                if use_flat and bz.draco_r == 0:
+                    if fallback_r > 0:
+                        arena = fake_quantize(arena, qdt)
+                    else:
+                        codes, qs = quantize_rows(arena, qdt)
                 else:
-                    codes, qs = quantize_rows(arena, qdt)
-            else:
-                sent = plan.unravel_stack(fake_quantize(arena, qdt))
-                arena = None
+                    sent = plan.unravel_stack(fake_quantize(arena, qdt))
+                    arena = None
+            elif (bz.draco_r == 0 and use_flat
+                  and plan.uniform_dtype is not None):
+                # ONE ravel into the (n, P) arena at the communication
+                # boundary; the single unravel happens below, at
+                # optimizer-apply
+                arena = plan.ravel(sent)
         if bucket is not None:
             w_b = jnp.where(roster_valid, contrib_w[roster_idx], 0.0)
-        if bz.draco_r > 0:
-            # coded regime: the repetition code already handles partial
-            # delivery (vote among delivered group members); under elastic
-            # membership the PACKED live rows are regrouped by the
-            # bucket's table (exact in the parallel regime — every agent
-            # computes the same shard).  tree_draco_aggregate rides the
-            # (n, P) arena internally for uniform-dtype trees.
-            if bucket is not None:
+        vec = None
+        with jax.named_scope("aggregate"):
+            if bz.draco_r > 0:
+                # coded regime: the repetition code already handles
+                # partial delivery (vote among delivered group members);
+                # under elastic membership the PACKED live rows are
+                # regrouped by the bucket's table (exact in the parallel
+                # regime — every agent computes the same shard).
+                # tree_draco_aggregate rides the (n, P) arena internally
+                # for uniform-dtype trees.
+                if bucket is not None:
+                    sent_b = jax.tree.map(lambda l: l[roster_idx], sent)
+                    agg = tree_draco_aggregate(sent_b, bz.draco_r,
+                                               mask=w_b > 0.0,
+                                               groups=groups)
+                else:
+                    agg = tree_draco_aggregate(sent, bz.draco_r,
+                                               mask=mask, groups=groups)
+            elif use_flat and (quant or plan.uniform_dtype is not None):
+                # the (n, P) arena raveled above: the quorum mask and
+                # staleness discounts enter the masked kernels as traced
+                # operands.  Mixed-dtype trees keep the tree path: a fp32
+                # arena would impute masked rows without each leaf's
+                # native rounding (not bitwise) — except under quantized
+                # exchange, which erases leaf dtypes anyway.
+                wire = codes if codes is not None else arena
+                if bucket is not None:
+                    rows, rmask, rw = wire[roster_idx], w_b > 0.0, w_b
+                    rqs = qs[roster_idx] if qs is not None else None
+                else:
+                    rows, rmask, rw = wire, mask, contrib_w
+                    rqs = qs
+                vec = spec.aggregate_flat(
+                    rows, mask=rmask, weights=rw, scale=rqs,
+                    state=agg_state if stateful else None)
+                if fallback_r > 0:
+                    # quorum missed: decode the repetition code over the
+                    # SAME arena rows (both candidates are (P,) fp32 — one
+                    # select, one unravel; under quant, rows are the
+                    # fake-quantized fp32 arena — codes are only cut when
+                    # fallback_r == 0)
+                    coded = flat_draco_aggregate(rows, fallback_r,
+                                                 mask=rmask, groups=groups)
+                    vec = jnp.where(use_coded, coded, vec)
+            elif bucket is not None:
+                # elastic membership: pack the live rows into the
+                # bucket's fixed-shape stack; pad slots (repeated live
+                # rows) are masked out, so the rule runs its per-bucket
+                # (n, f) plan over the live roster only
                 sent_b = jax.tree.map(lambda l: l[roster_idx], sent)
-                agg = tree_draco_aggregate(sent_b, bz.draco_r,
-                                           mask=w_b > 0.0, groups=groups)
+                agg = spec.aggregate(sent_b, mask=w_b > 0.0, weights=w_b,
+                                     state=agg_state if stateful else None)
+                if fallback_r > 0:
+                    coded = tree_draco_aggregate(sent_b, fallback_r,
+                                                 mask=w_b > 0.0,
+                                                 groups=groups)
+                    agg = jax.tree.map(
+                        lambda a, c: jnp.where(use_coded,
+                                               c.astype(a.dtype), a),
+                        agg, coded)
             else:
-                agg = tree_draco_aggregate(sent, bz.draco_r, mask=mask,
-                                           groups=groups)
-        elif use_flat and (quant or plan.uniform_dtype is not None):
-            # ONE ravel into the (n, P) arena at the communication
-            # boundary; the quorum mask and staleness discounts enter the
-            # masked kernels as traced operands and the single unravel
-            # happens below, at optimizer-apply.  Mixed-dtype trees keep
-            # the tree path: a fp32 arena would impute masked rows
-            # without each leaf's native rounding (not bitwise) — except
-            # under quantized exchange, which erases leaf dtypes anyway.
-            if arena is None:
-                arena = plan.ravel(sent)
-            wire = codes if codes is not None else arena
-            if bucket is not None:
-                rows, rmask, rw = wire[roster_idx], w_b > 0.0, w_b
-                rqs = qs[roster_idx] if qs is not None else None
-            else:
-                rows, rmask, rw = wire, mask, contrib_w
-                rqs = qs
-            vec = spec.aggregate_flat(rows, mask=rmask, weights=rw,
-                                      scale=rqs,
-                                      state=agg_state if stateful else None)
-            if fallback_r > 0:
-                # quorum missed: decode the repetition code over the SAME
-                # arena rows (both candidates are (P,) fp32 — one select,
-                # one unravel; under quant, rows are the fake-quantized
-                # fp32 arena — codes are only cut when fallback_r == 0)
-                coded = flat_draco_aggregate(rows, fallback_r, mask=rmask,
-                                             groups=groups)
-                vec = jnp.where(use_coded, coded, vec)
-            agg = plan.unravel(vec)
-        elif bucket is not None:
-            # elastic membership: pack the live rows into the bucket's
-            # fixed-shape stack; pad slots (repeated live rows) are masked
-            # out, so the rule runs its per-bucket (n, f) plan over the
-            # live roster only
-            sent_b = jax.tree.map(lambda l: l[roster_idx], sent)
-            agg = spec.aggregate(sent_b, mask=w_b > 0.0, weights=w_b,
-                                 state=agg_state if stateful else None)
-            if fallback_r > 0:
-                coded = tree_draco_aggregate(sent_b, fallback_r,
-                                             mask=w_b > 0.0, groups=groups)
-                agg = jax.tree.map(
-                    lambda a, c: jnp.where(use_coded, c.astype(a.dtype), a),
-                    agg, coded)
-        else:
-            agg = spec.aggregate(sent, mask=mask, weights=contrib_w,
-                                 state=agg_state if stateful else None)
-            if fallback_r > 0:
-                coded = tree_draco_aggregate(sent, fallback_r, mask=mask,
-                                             groups=groups)
-                agg = jax.tree.map(
-                    lambda a, c: jnp.where(use_coded, c.astype(a.dtype), a),
-                    agg, coded)
+                agg = spec.aggregate(sent, mask=mask, weights=contrib_w,
+                                     state=agg_state if stateful else None)
+                if fallback_r > 0:
+                    coded = tree_draco_aggregate(sent, fallback_r,
+                                                 mask=mask, groups=groups)
+                    agg = jax.tree.map(
+                        lambda a, c: jnp.where(use_coded,
+                                               c.astype(a.dtype), a),
+                        agg, coded)
+        if vec is not None:
+            with jax.named_scope("unravel"):
+                agg = plan.unravel(vec)
         telem = None
         if telemetry:
             # fixed-shape (n,) aux outputs, computed OUTSIDE the aggregate
@@ -368,8 +387,9 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
             agg_state = {"agg": agg_state, "atk": atk_state}
 
         # (4) server-side optimizer
-        updates, opt_state = optimizer.update(agg, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(agg, opt_state, params)
+            params = apply_updates(params, updates)
 
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(l.astype(jnp.float32)))
@@ -385,6 +405,52 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
         return params, opt_state, momentum, buffer, agg_state, metrics
 
     return async_step
+
+
+def _record_step(recorder, step, st0, metrics, telem, atrace, arrived,
+                 roster):
+    """Feed one step's metrics, telemetry and faults to the recorder
+    (``float()`` syncs on the step's outputs)."""
+    mrec = ({k: float(v) for k, v in metrics.items()}
+            if metrics is not None else {})
+    mrec["arrived"] = arrived
+    mrec["n_live"] = atrace.n_live(step)
+    mrec["staleness_mean"] = (
+        float(atrace.staleness[step][atrace.contrib[step]].mean())
+        if arrived else 0.0)
+    mrec["staleness_max"] = (
+        int(atrace.staleness[step][atrace.contrib[step]].max())
+        if arrived else 0)
+    mrec["quorum_ok"] = bool(atrace.quorum_met[step])
+    if not atrace.quorum_met[step]:
+        recorder.fault(step, "quorum_miss", arrived=arrived)
+    recorder.step(step, t0=st0, t1=recorder.now(), metrics=mrec,
+                  telemetry=telem,
+                  roster=(roster[step] if roster is not None else None))
+
+
+def _log_step(history, step, metrics, atrace, arrived, pure, t0,
+              log_fn) -> None:
+    """Append one step's row to ``history`` and print it through
+    ``log_fn`` (``float()`` syncs on the step's outputs)."""
+    if metrics is None:
+        m = {"loss": float("nan"), "loss_all": float("nan"),
+             "grad_norm": 0.0}
+    else:
+        m = {k: float(v) for k, v in metrics.items()}
+    m["step"] = step
+    m["wall_s"] = time.time() - t0
+    m["arrived"] = arrived
+    m["n_live"] = atrace.n_live(step)
+    m["staleness_mean"] = (
+        float(atrace.staleness[step][atrace.contrib[step]].mean())
+        if arrived else 0.0)
+    m["vclock"] = float(atrace.vclock[step])
+    history.append(m)
+    extra = ("" if pure else
+             f"  arr {arrived:2d}  stal {m['staleness_mean']:.2f}")
+    log_fn(f"step {step:5d}  loss {m['loss']:.4f}  "
+           f"gnorm {m['grad_norm']:.3f}{extra}")
 
 
 def async_train_loop(cfg, bz, optimizer, dataset, steps: int,
@@ -540,82 +606,61 @@ def async_train_loop(cfg, bz, optimizer, dataset, steps: int,
     history = []
     t0 = time.time()
     for step in range(steps):
-        k_run, k_data, k_step = jax.random.split(k_run, 3)
-        batch = dataset.batch(k_data, step)
-        if poison_labels:
-            batch = label_flip(batch, byz_mask, cfg.vocab_size)
-        arrived = int(atrace.contrib[step].sum())
-        st0 = recorder.now() if recorder is not None else None
-        if pure[step]:
-            params, opt_state, momentum, metrics = step_fn(
-                params, opt_state, momentum, batch, k_step)
-        elif arrived == 0:
-            # nobody delivered: version unchanged, defer this step's
-            # dispatches to the next step that actually runs
-            pending_refresh |= atrace.refresh[step]
-            metrics = None
-        else:
-            refresh = atrace.refresh[step] | pending_refresh
-            pending_refresh = np.zeros(n, bool)
-            use_coded = bool(not atrace.quorum_met[step]
-                             and sim.coded_fallback_r > 0)
-            if el is not None:
-                # pack the live roster into its bucket's fixed shape
-                # (arrived > 0 here, and contributors are members, so the
-                # roster row has at least one live agent)
-                b, idx, valid = el.pack(np.flatnonzero(roster[step]))
-                (params, opt_state, momentum, buffer, agg_state,
-                 metrics) = bucket_fn(int(b))(
-                    params, opt_state, momentum, buffer, agg_state, batch,
-                    k_step, jnp.asarray(refresh),
-                    jnp.asarray(contrib_w[step]), jnp.asarray(use_coded),
-                    jnp.asarray(idx), jnp.asarray(valid))
-            else:
-                (params, opt_state, momentum, buffer, agg_state,
-                 metrics) = async_fn(
-                    params, opt_state, momentum, buffer, agg_state, batch,
-                    k_step, jnp.asarray(refresh),
-                    jnp.asarray(contrib_w[step]), jnp.asarray(use_coded))
-        telem = metrics.pop("telemetry", None) if metrics else None
-        if recorder is not None:
-            mrec = ({k: float(v) for k, v in metrics.items()}
-                    if metrics is not None else {})
-            mrec["arrived"] = arrived
-            mrec["n_live"] = atrace.n_live(step)
-            mrec["staleness_mean"] = (
-                float(atrace.staleness[step][atrace.contrib[step]].mean())
-                if arrived else 0.0)
-            mrec["staleness_max"] = (
-                int(atrace.staleness[step][atrace.contrib[step]].max())
-                if arrived else 0)
-            mrec["quorum_ok"] = bool(atrace.quorum_met[step])
-            if not atrace.quorum_met[step]:
-                recorder.fault(step, "quorum_miss", arrived=arrived)
-            recorder.step(step, t0=st0, t1=recorder.now(), metrics=mrec,
-                          telemetry=telem,
-                          roster=(roster[step] if roster is not None
-                                  else None))
-        if step % log_every == 0 or step == steps - 1:
-            if metrics is None:
-                m = {"loss": float("nan"), "loss_all": float("nan"),
-                     "grad_norm": 0.0}
-            else:
-                m = {k: float(v) for k, v in metrics.items()}
-            m["step"] = step
-            m["wall_s"] = time.time() - t0
-            m["arrived"] = arrived
-            m["n_live"] = atrace.n_live(step)
-            m["staleness_mean"] = (
-                float(atrace.staleness[step][atrace.contrib[step]].mean())
-                if arrived else 0.0)
-            m["vclock"] = float(atrace.vclock[step])
-            history.append(m)
-            extra = ("" if pure[step] else
-                     f"  arr {arrived:2d}  stal {m['staleness_mean']:.2f}")
-            log_fn(f"step {step:5d}  loss {m['loss']:.4f}  "
-                   f"gnorm {m['grad_norm']:.3f}{extra}")
-        if ckpt_dir and ckpt_every and step and step % ckpt_every == 0:
-            save(ckpt_dir, step, {"params": params, "opt": opt_state})
+        with step_span(TRAIN_STEP, step):
+            k_run, k_data, k_step = jax.random.split(k_run, 3)
+            with span(TRAIN_BATCH):
+                batch = dataset.batch(k_data, step)
+            if poison_labels:
+                batch = label_flip(batch, byz_mask, cfg.vocab_size)
+            arrived = int(atrace.contrib[step].sum())
+            st0 = recorder.now() if recorder is not None else None
+            with span(TRAIN_DISPATCH):
+                if pure[step]:
+                    params, opt_state, momentum, metrics = step_fn(
+                        params, opt_state, momentum, batch, k_step)
+                elif arrived == 0:
+                    # nobody delivered: version unchanged, defer this
+                    # step's dispatches to the next step that actually runs
+                    pending_refresh |= atrace.refresh[step]
+                    metrics = None
+                else:
+                    refresh = atrace.refresh[step] | pending_refresh
+                    pending_refresh = np.zeros(n, bool)
+                    use_coded = bool(not atrace.quorum_met[step]
+                                     and sim.coded_fallback_r > 0)
+                    if el is not None:
+                        # pack the live roster into its bucket's fixed
+                        # shape (arrived > 0 here, and contributors are
+                        # members, so the roster row has a live agent)
+                        b, idx, valid = el.pack(np.flatnonzero(roster[step]))
+                        (params, opt_state, momentum, buffer, agg_state,
+                         metrics) = bucket_fn(int(b))(
+                            params, opt_state, momentum, buffer, agg_state,
+                            batch, k_step, jnp.asarray(refresh),
+                            jnp.asarray(contrib_w[step]),
+                            jnp.asarray(use_coded), jnp.asarray(idx),
+                            jnp.asarray(valid))
+                    else:
+                        (params, opt_state, momentum, buffer, agg_state,
+                         metrics) = async_fn(
+                            params, opt_state, momentum, buffer, agg_state,
+                            batch, k_step, jnp.asarray(refresh),
+                            jnp.asarray(contrib_w[step]),
+                            jnp.asarray(use_coded))
+            telem = metrics.pop("telemetry", None) if metrics else None
+            if recorder is not None:
+                with span(TRAIN_LOG):
+                    _record_step(recorder, step, st0, metrics, telem,
+                                 atrace, arrived, roster)
+            if step % log_every == 0 or step == steps - 1:
+                with span(TRAIN_LOG):
+                    _log_step(history, step, metrics, atrace, arrived,
+                              pure[step], t0, log_fn)
+            if ckpt_dir and ckpt_every and step and step % ckpt_every == 0:
+                with span(TRAIN_CHECKPOINT):
+                    save(ckpt_dir, step, {"params": params,
+                                          "opt": opt_state})
     if ckpt_dir:
-        save(ckpt_dir, steps, {"params": params, "opt": opt_state})
+        with span(TRAIN_CHECKPOINT):
+            save(ckpt_dir, steps, {"params": params, "opt": opt_state})
     return params, history

@@ -240,94 +240,108 @@ def make_train_step(cfg, bz: ByzantineConfig, optimizer,
 
         from repro.distributed.context import layer_remat
         ctx = layer_remat(True) if bz.remat else contextlib.nullcontext()
-        with ctx:
-            losses, grads = jax.vmap(
-                jax.value_and_grad(agent_loss), in_axes=(None, 0))(
-                    params, batch)
+        with jax.named_scope("grads"):
+            with ctx:
+                losses, grads = jax.vmap(
+                    jax.value_and_grad(agent_loss), in_axes=(None, 0))(
+                        params, batch)
 
-        # variance reduction: agents send momentum, not raw gradients
-        if bz.momentum_alpha > 0.0:
-            momentum, grads = worker_momentum(momentum, grads,
-                                              bz.momentum_alpha)
+            # variance reduction: agents send momentum, not raw gradients
+            if bz.momentum_alpha > 0.0:
+                momentum, grads = worker_momentum(momentum, grads,
+                                                  bz.momentum_alpha)
 
         # (3) Byzantine injection at the communication boundary
         if attack_fn is not None:
-            grads = tree_attack(attack_fn, key, grads, byz_mask)
+            with jax.named_scope("attack"):
+                grads = tree_attack(attack_fn, key, grads, byz_mask)
 
         # (4) robust aggregation via the AggregatorSpec (+ §Perf variants)
-        if bz.agg_dtype and not quant:
-            grads = jax.tree.map(
-                lambda l: l.astype(jnp.dtype(bz.agg_dtype)), grads)
-        if bz.group_size > 1:
-            grads = _group_mean(grads, bz.group_size)
-        if bz.reshard and mesh_sizes:
-            grads = jax.lax.with_sharding_constraint(
-                grads, _reshard_specs(grads, mesh_sizes))
-        plan = FlatPlan.for_tree(grads)
-        codes = qs = None
-        if quant:
-            # quantize the wire: per-row codes + fp32 scale sidecar.  The
-            # pre-quantization f32 arena is kept ONLY as a local for
-            # telemetry (it exists anyway — it's what was quantized);
-            # the aggregate itself sees codes + scale.  Paths without a
-            # scale-aware entry point (coded votes, reshard, non-flat
-            # specs) see the fake-quantized stack instead, so every path
-            # has identical compressed-exchange semantics.
-            arena = plan.ravel(grads, jnp.float32)
-            if use_flat and bz.draco_r == 0:
-                codes, qs = quantize_rows(arena, jnp.dtype(bz.agg_dtype))
-            else:
-                grads = plan.unravel_stack(
-                    fake_quantize(arena, jnp.dtype(bz.agg_dtype)))
-        if bz.draco_r > 0:
-            if bucket is not None:
-                # elastic membership: regroup the packed live rows with
-                # the bucket's table; pad slots are masked out of the vote
+        with jax.named_scope("arena"):
+            if bz.agg_dtype and not quant:
+                grads = jax.tree.map(
+                    lambda l: l.astype(jnp.dtype(bz.agg_dtype)), grads)
+            if bz.group_size > 1:
+                grads = _group_mean(grads, bz.group_size)
+            if bz.reshard and mesh_sizes:
+                grads = jax.lax.with_sharding_constraint(
+                    grads, _reshard_specs(grads, mesh_sizes))
+            plan = FlatPlan.for_tree(grads)
+            codes = qs = None
+            if quant:
+                # quantize the wire: per-row codes + fp32 scale sidecar.
+                # The pre-quantization f32 arena is kept ONLY as a local
+                # for telemetry (it exists anyway — it's what was
+                # quantized); the aggregate itself sees codes + scale.
+                # Paths without a scale-aware entry point (coded votes,
+                # reshard, non-flat specs) see the fake-quantized stack
+                # instead, so every path has identical compressed-
+                # exchange semantics.
+                arena = plan.ravel(grads, jnp.float32)
+                if use_flat and bz.draco_r == 0:
+                    codes, qs = quantize_rows(arena, jnp.dtype(bz.agg_dtype))
+                else:
+                    grads = plan.unravel_stack(
+                        fake_quantize(arena, jnp.dtype(bz.agg_dtype)))
+            elif (bz.draco_r == 0 and use_flat
+                  and plan.uniform_dtype is not None):
+                # zero-copy: ONE ravel into the (n, P) arena here, the
+                # aggregation runs on the arena, and the single unravel
+                # below happens at optimizer-apply — plan offsets are
+                # precomputed (FlatPlan is cached per tree structure), so
+                # the dispatch itself moves no model-sized memory.
+                # Mixed-dtype trees keep the tree path: flattening them
+                # would impute masked rows at fp32 instead of each leaf's
+                # native rounding (not bitwise).
+                arena = plan.ravel(grads)
+        vec = None
+        with jax.named_scope("aggregate"):
+            if bz.draco_r > 0:
+                if bucket is not None:
+                    # elastic membership: regroup the packed live rows
+                    # with the bucket's table; pad slots are masked out of
+                    # the vote
+                    live = jax.tree.map(lambda l: l[roster_idx], grads)
+                    agg = tree_draco_aggregate(live, bz.draco_r,
+                                               mask=roster_valid,
+                                               groups=groups)
+                else:
+                    agg = tree_draco_aggregate(grads, bz.draco_r,
+                                               groups=groups)
+            elif codes is not None:
+                # compressed flat path: codes on the wire, per-row scale
+                # as a sidecar operand — the scaled kernels dequantize
+                # in-tile (no (n, P) f32 copy; mixed-dtype trees are fine
+                # here since the exchange dtype erases per-leaf dtypes)
+                if bucket is not None:
+                    vec = spec.aggregate_flat(codes[roster_idx],
+                                              mask=roster_valid,
+                                              scale=qs[roster_idx])
+                else:
+                    vec = spec.aggregate_flat(codes, scale=qs)
+            elif use_flat and plan.uniform_dtype is not None:
+                if bucket is not None:
+                    vec = spec.aggregate_flat(arena[roster_idx],
+                                              mask=roster_valid)
+                else:
+                    vec = spec.aggregate_flat(arena)
+            elif bucket is not None:
+                # elastic membership: the rule sees only the live roster,
+                # packed into the bucket's fixed-shape stack (pad slots
+                # are repeated live rows, masked out under the documented
+                # masked semantics)
                 live = jax.tree.map(lambda l: l[roster_idx], grads)
-                agg = tree_draco_aggregate(live, bz.draco_r,
-                                           mask=roster_valid, groups=groups)
+                agg = spec.aggregate(live, mask=roster_valid)
             else:
-                agg = tree_draco_aggregate(grads, bz.draco_r, groups=groups)
-        elif codes is not None:
-            # compressed flat path: codes on the wire, per-row scale as a
-            # sidecar operand — the scaled kernels dequantize in-tile (no
-            # (n, P) f32 copy; mixed-dtype trees are fine here since the
-            # exchange dtype erases per-leaf dtypes anyway)
-            if bucket is not None:
-                vec = spec.aggregate_flat(codes[roster_idx],
-                                          mask=roster_valid,
-                                          scale=qs[roster_idx])
-            else:
-                vec = spec.aggregate_flat(codes, scale=qs)
-            agg = plan.unravel(vec)
-        elif use_flat and plan.uniform_dtype is not None:
-            # zero-copy: ONE ravel into the (n, P) arena here, the
-            # aggregation runs on the arena, and the single unravel below
-            # happens at optimizer-apply — plan offsets are precomputed
-            # (FlatPlan is cached per tree structure), so the dispatch
-            # itself moves no model-sized memory.  Mixed-dtype trees keep
-            # the tree path: flattening them would impute masked rows at
-            # fp32 instead of each leaf's native rounding (not bitwise).
-            arena = plan.ravel(grads)
-            if bucket is not None:
-                vec = spec.aggregate_flat(arena[roster_idx],
-                                          mask=roster_valid)
-            else:
-                vec = spec.aggregate_flat(arena)
-            agg = plan.unravel(vec)
-        elif bucket is not None:
-            # elastic membership: the rule sees only the live roster,
-            # packed into the bucket's fixed-shape stack (pad slots are
-            # repeated live rows, masked out under the documented masked
-            # semantics)
-            live = jax.tree.map(lambda l: l[roster_idx], grads)
-            agg = spec.aggregate(live, mask=roster_valid)
-        else:
-            agg = spec.aggregate(grads)
+                agg = spec.aggregate(grads)
+        if vec is not None:
+            with jax.named_scope("unravel"):
+                agg = plan.unravel(vec)
 
         # (5) server-side optimizer
-        updates, opt_state = optimizer.update(agg, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(agg, opt_state, params)
+            params = apply_updates(params, updates)
 
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(l.astype(jnp.float32)))

@@ -72,10 +72,8 @@ def test_counter_snapshot_delta():
     before = counters.snapshot()
     counters.inc("obs_test_site")
     counters.inc("obs_test_site")
-    counters.set_gauge("obs_test_gauge", 7)
     delta = counters.counter_delta(before)
     assert delta.get("obs_test_site") == 2
-    assert counters.gauge("obs_test_gauge") == 7
     after = counters.snapshot()
     assert after["counters"]["obs_test_site"] - \
         before["counters"].get("obs_test_site", 0) == 2
