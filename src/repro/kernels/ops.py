@@ -6,7 +6,9 @@ masked/weighted variants).
 ``interpret=None`` (the default) resolves per process through
 :func:`repro.kernels.tiling.default_interpret`: compiled Mosaic kernels on a
 TPU backend (the BlockSpecs are TPU-shaped: n sublanes x 512 lanes, fp32
-accumulation), the interpreter elsewhere.  Pass a bool to force either."""
+accumulation; coord_stat reads a lane-dense fp32 arena in its own T(8, 128)
+layout instead, see repro.kernels.coord_stats), the interpreter elsewhere.
+Pass a bool to force either."""
 from __future__ import annotations
 
 import functools

@@ -1,7 +1,11 @@
 """Shared tile-width and interpret-mode policy for the aggregation kernels.
 
 Compiled TPU kernels tile the huge d axis into VMEM-resident TILE_D-lane
-blocks (n sublanes x 512 lanes, fp32).  Interpret mode has no VMEM to
+blocks (n sublanes x 512 lanes, fp32): the Gram, weighted-sum, selection,
+masked, scaled, sign-vote and Bulyan kernels, coord_sort, and coord_stat
+on stacks that are not lane-dense.  coord_stat on an fp32 stack with
+n % 8 == 0 and d % 128 == 0 reads the arena's own T(8, 128) layout in
+blocks of its own (repro.kernels.coord_stats).  Interpret mode has no VMEM to
 respect, but it DOES pay the interpreter's per-grid-step dispatch cost
 (~10 ms/step): at model scale (d ~ 1e6 -> thousands of tiles) a tiled
 grid turns one aggregation into tens of seconds on CPU.  So off-TPU the

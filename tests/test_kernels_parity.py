@@ -258,11 +258,38 @@ def test_selection_family_survives_nonfinite_adversary():
 # 2. raw-kernel parity vs the pure-jnp oracles in kernels/ref.py
 
 
-@pytest.mark.parametrize("n", NS)
+@pytest.mark.parametrize("d", [1024, 3000])       # 3000 % 128 != 0
+@pytest.mark.parametrize("n", [8, 9, 12, 16])
 @pytest.mark.parametrize("stat,b", [("median", 0), ("trimmed_mean", 2)])
-def test_coord_stat_matches_oracle(n, stat, b):
-    g = data(n, 1024, jnp.float32, 5)
+def test_coord_stat_matches_oracle(n, d, stat, b):
+    """Both tilings of coord_stat: lane-dense where n % 8 == 0 and
+    d % 128 == 0, (n, TILE_D) blocks otherwise; the counter names the
+    path that traced."""
+    from repro.obs import counters
+    g = data(n, d, jnp.float32, 5)
+    path = "lane_dense" if n % 8 == 0 and d % 128 == 0 else "tiled"
+    coord_stat.clear_cache()             # trace afresh: the counter moves
+    before = counters.snapshot()
     out = coord_stat(g, stat, b=b)
+    moved = counters.counter_delta(before)
+    assert moved.get(f"kernels.coord_stat.{path}") == 1, moved
+    expect = (ref.median_from_sorted if stat == "median"
+              else lambda s: ref.trimmed_mean_from_sorted(s, b))(
+                  jnp.sort(g, axis=0))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+
+
+@pytest.mark.parametrize("rows,strip", [(16, 16), (24, 16), (40, 8)])
+@pytest.mark.parametrize("n", [8, 16])
+@pytest.mark.parametrize("stat,b", [("median", 0), ("trimmed_mean", 2)])
+def test_coord_stat_lane_dense_blocks_match_oracle(n, rows, strip, stat, b):
+    """The chip's lane-dense block arithmetic under the interpreter: a
+    grid of several blocks with a ragged last one (40 rows of 128 lanes
+    per agent), a tail strip (24 = 16 + 8) and a multi-strip loop."""
+    from repro.kernels.coord_stats import _coord_stat_lane_dense
+    g = data(n, 40 * 128, jnp.float32, 9)
+    out = _coord_stat_lane_dense(g, stat, b, rows=rows, strip=strip,
+                                 interpret=True)
     expect = (ref.median_from_sorted if stat == "median"
               else lambda s: ref.trimmed_mean_from_sorted(s, b))(
                   jnp.sort(g, axis=0))

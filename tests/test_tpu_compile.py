@@ -121,6 +121,37 @@ def test_kernel_compiles_for_v5e(one_chip, kind, rule, n):
     assert "tpu_custom_call" in compiled.as_text(), (kind, rule, n)
 
 
+def test_coord_stat_reads_the_arena_in_place(one_chip):
+    """trimmed_mean on an (8, P) f32 arena cast from bf16 inside the jit
+    (as the train step makes it) takes the lane-dense coord_stat: one
+    ``tpu_custom_call`` named ``coord_stat`` (the benchmark's aggregation
+    reader finds it by that name), fed a bitcast of the arena's own
+    T(8, 128) layout, with no arena-sized relayout copy or transpose."""
+    from repro.kernels.coord_stats import lane_dense
+    n = 8
+    arena = jax.ShapeDtypeStruct((n, P), jnp.float32)
+    assert lane_dense(arena)
+    fn = lambda g: dispatch.pallas_aggregate(            # noqa: E731
+        "trimmed_mean", g.astype(jnp.float32), F, interpret=False)
+    text = _compile(fn, _sds(one_chip, (n, P), jnp.bfloat16)).as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 1 and calls[0].startswith("%coord_stat"), calls
+    relayouts = [ln.strip() for ln in text.splitlines()
+                 if (" copy(" in ln or " transpose(" in ln)
+                 and "f32[" in ln and _elements(ln) >= n * P]
+    assert not relayouts, relayouts
+
+
+def _elements(hlo_line: str) -> int:
+    """Element count of an HLO instruction's f32 result shape."""
+    dims = hlo_line.split("f32[", 1)[1].split("]", 1)[0]
+    out = 1
+    for x in dims.split(","):
+        out *= int(x) if x else 1
+    return out
+
+
 def _step_shapes(one_chip, cfg, n, seq, batch, opt):
     from repro.data import SyntheticLM
     from repro.models import init_params
