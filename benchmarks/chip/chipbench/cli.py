@@ -51,7 +51,11 @@ def _finite(x: float) -> float:
 def main(argv, root: str, t_start: float, gate=device_gate,
          bench_dir: str = spec_mod.BENCH_DIR) -> int:
     args = parse(argv)
-    cell = spec_mod.load_cell(root, args.workload, bench_dir)
+    try:
+        cell = spec_mod.load_cell(root, args.workload, bench_dir)
+    except (LookupError, ValueError, FileNotFoundError) as e:
+        _say(f"chipbench: refused before any run: {e}")
+        return 2
     try:
         devices = gate(cell.chips)
     except NoChip as e:

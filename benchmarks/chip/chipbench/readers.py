@@ -2,8 +2,10 @@
 
 A reader gets the run's namespace: ``kind`` ("train" | "serve"), the
 trace's plain form ``plain``, ``busy_s`` and ``trace_window_s``, the
-chip's ``peak`` and what the run measured.  It returns None where it
-finds nothing to read."""
+chip's ``peak``, the configuration's ``model`` block and what the run
+measured; a training run also carries ``seq`` and ``flops_per_token``
+(the configuration's reference module counts it).  It returns None
+where it finds nothing to read."""
 from __future__ import annotations
 
 import re

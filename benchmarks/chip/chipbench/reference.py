@@ -1,13 +1,13 @@
-"""The plain reference of the dense decoder LM both configurations run,
-and of the robust-training rules the training cells use.
+"""The plain reference's parts that every configuration shares: the
+matmul at the reference's precision and its control, and the
+robust-training rules, attacks, delivery schedule and optimizer that the
+training cells use.
 
 Straightforward ``jax.numpy`` in float32 at ``HIGHEST`` matmul
-precision, with no kernels, cache or batching tricks; it imports nothing
-of the program.  Parameters are read by name from the tree the benchmark
-makes (``chipbench.weights``).  Layer equations: pre-norm blocks with
-RMSNorm scaled by (1 + w), grouped-query attention with rotary positions
-(rotate-half, base ``rope_theta``) and a causal mask, a SwiGLU MLP, a
-final RMSNorm and an untied output head.
+precision; it imports nothing of the program.  A configuration's model
+is checked by the module that its ``"reference"`` key names (for
+example ``benchmarks/chip/refs/dense_decoder.py``), which may build on
+``mm``.
 
 ``mode`` selects the arithmetic: ``"f32"`` is the reference; ``"fp8"``
 rounds every matmul operand to float8_e4m3fn first, the control that a
@@ -32,70 +32,6 @@ def _op(x, mode: str):
 def mm(eq: str, a, b, mode: str = "f32"):
     return jnp.einsum(eq, _op(a, mode), _op(b, mode), precision=HIGHEST,
                       preferred_element_type=jnp.float32)
-
-
-def rms_norm(x, w, eps):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1.0 + w.astype(jnp.float32))
-
-
-def rope(x, theta):
-    """x: (B, T, heads, hd), rotated by position t along its two halves."""
-    t = x.shape[1]
-    half = x.shape[-1] // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv       # (T, half)
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def block(m: dict, blk: dict, x, mode: str):
-    """One decoder block on (B, T, D) float32."""
-    b, t, d = x.shape
-    h, k = m["num_heads"], m["num_kv_heads"]
-    hd = m.get("head_dim") or d // h
-    g = h // k
-    eps = m.get("norm_eps", 1e-5)
-    a = rms_norm(x, blk["attn_norm"], eps)
-    at = blk["attn"]
-    q = mm("btd,de->bte", a, at["wq"], mode).reshape(b, t, h, hd)
-    kk = mm("btd,de->bte", a, at["wk"], mode).reshape(b, t, k, hd)
-    v = mm("btd,de->bte", a, at["wv"], mode).reshape(b, t, k, hd)
-    theta = m.get("rope_theta", 10000.0)
-    q, kk = rope(q, theta), rope(kk, theta)
-    # query head j reads kv head j // g
-    q = q.reshape(b, t, k, g, hd)
-    s = mm("btkgh,bskh->bkgts", q, kk, mode) / jnp.sqrt(jnp.float32(hd))
-    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = mm("bkgts,bskh->btkgh", p, v, mode).reshape(b, t, h * hd)
-    x = x + mm("bte,ed->btd", o, at["wo"], mode)
-    a = rms_norm(x, blk["mlp_norm"], eps)
-    ml = blk["mlp"]
-    gate = mm("btd,df->btf", a, ml["w_gate"], mode)
-    up = mm("btd,df->btf", a, ml["w_up"], mode)
-    return x + mm("btf,fd->btd", jax.nn.silu(gate) * up, ml["w_down"], mode)
-
-
-def logits(m: dict, params: dict, tokens, mode: str = "f32"):
-    """(B, T) int tokens -> (B, T, V) float32 logits."""
-    x = params["embed"].astype(jnp.float32)[tokens]
-
-    def body(h, blk):
-        return block(m, blk, h, mode), None
-    x, _ = jax.lax.scan(body, x, params["blocks"])
-    x = rms_norm(x, params["final_norm"], m.get("norm_eps", 1e-5))
-    return mm("btd,dv->btv", x, params["lm_head"], mode)
-
-
-def loss(m: dict, params: dict, batch: dict, mode: str = "f32"):
-    """Mean next-token NLL over every position of the batch."""
-    lg = logits(m, params, batch["tokens"], mode)
-    logp = jax.nn.log_softmax(lg, axis=-1)
-    nll = -jnp.take_along_axis(logp, batch["labels"][..., None], -1)[..., 0]
-    return jnp.mean(nll)
 
 
 # ---------------------------------------------------------------------------

@@ -77,8 +77,7 @@ def run(cell, args, clock, say):
     seconds = (min(args.seconds, tr["trace_seconds"]) if args.trace
                else args.seconds)
     with clock.phase("build"):
-        cfg = ArchConfig(name=cell.config_name, family="dense",
-                         **cell.config["model"])
+        cfg = ArchConfig(name=cell.config_name, **cell.config["model"])
         shapes = weights.param_shapes(cfg)
         r, nbad = dep["replicas"], tr["bad_replicas"]
         spec = make_spec(dep["vote"], f=dep["f"], n=r)
@@ -231,29 +230,29 @@ def sample_requests(finished: list, reqs: list, seed: int, tokens: int):
 def reference_gaps(cell, seed: int, checked: list, control: bool = False):
     """Widest gap, over every served token of ``checked``, between the
     reference's best logit and the served token's; with ``control``, also
-    the same for the token that the fp8 reference puts first."""
+    the same for the token that the fp8 reference puts first.  The model
+    is the configuration's reference module (``cell.reference``)."""
     import jax
     import jax.numpy as jnp
 
-    from chipbench import reference as ref
     from repro.configs.base import ArchConfig
     if not checked:
         return float("inf"), float("inf")
+    lm = cell.reference
     m, dep = cell.config["model"], cell.config["deployment"]
     cap = dep["seq_capacity"]
-    shapes = weights.param_shapes(ArchConfig(name="ref", family="dense",
-                                             **m))
+    shapes = weights.param_shapes(ArchConfig(name="ref", **m))
     params = jax.tree.map(lambda x: x.astype(jnp.float32),
                           weights.make(shapes, (seed,)))
 
     @jax.jit
     def gaps(p, toks, targets):
-        lg = ref.logits(m, p, toks[None], "f32")[0]           # (cap, V)
+        lg = lm.logits(m, p, toks[None], "f32")[0]            # (cap, V)
         best = jnp.max(lg, axis=-1)
         g = best - jnp.take_along_axis(lg, targets[:, None], -1)[:, 0]
         if not control:
             return g, g
-        c = jnp.argmax(ref.logits(m, p, toks[None], "fp8")[0], axis=-1)
+        c = jnp.argmax(lm.logits(m, p, toks[None], "fp8")[0], axis=-1)
         return g, best - jnp.take_along_axis(lg, c[:, None], -1)[:, 0]
 
     worst = worst_c = 0.0

@@ -157,8 +157,7 @@ def _program(cell, seed):
     from repro.optim import adamw, constant
     from repro.training import ByzantineConfig
     dep, tr = cell.config["deployment"], cell.traffic
-    cfg = ArchConfig(name=cell.config_name, family="dense",
-                     **cell.config["model"])
+    cfg = ArchConfig(name=cell.config_name, **cell.config["model"])
     n, f = dep["n_agents"], dep["f"]
     spec = make_spec(tr["rule"], f=f, n=n, impl=tr.get("impl", "auto"),
                      **tr.get("rule_hyper", {}))
@@ -238,6 +237,8 @@ def run(cell, args, clock, say):
     batches = feed.batches
     run_ns = SimpleNamespace(
         kind="train", model=cell.config["model"], seq=tr["seq"],
+        flops_per_token=cell.reference.train_flops_per_token(
+            cell.config["model"], tr["seq"]),
         rule=tr["rule"], n_agents=dep["n_agents"], masked=sim is not None,
         elem_bytes=jax.numpy.dtype(cfg.dtype).itemsize,
         n_params=int(sum(np.prod(s.shape) for s in jax.tree.leaves(shapes))),
@@ -330,6 +331,8 @@ def reference_readings(cell, seed: int, batches: list,
     coordinate, parameters stored in the configuration's dtype between
     steps.  A selection rule sees undelivered rows at the weighted mean
     of the delivered ones and scales its result by their mean weight.
+    The model is the configuration's reference module
+    (``cell.reference``), the rest ``chipbench.reference``.
     ``variant`` puts a control in the program's place: "fp8" (matmul
     operands rounded to float8) or "halfbatch" (each agent's loss over
     the first half of its rows only)."""
@@ -337,13 +340,13 @@ def reference_readings(cell, seed: int, batches: list,
     import jax.numpy as jnp
 
     from chipbench import reference as ref
+    lm = cell.reference
     m, dep, tr = cell.config["model"], cell.config["deployment"], \
         cell.traffic
     n, f, o = dep["n_agents"], dep["f"], dep["optimizer"]
     mode = "fp8" if variant == "fp8" else "f32"
     from repro.configs.base import ArchConfig
-    shapes = weights.param_shapes(ArchConfig(name="ref", family="dense",
-                                             **m))
+    shapes = weights.param_shapes(ArchConfig(name="ref", **m))
     store = jnp.dtype(m.get("dtype", "bfloat16"))
     hyper = tr.get("attack_hyper", {})
     attack = lambda x: ref.ATTACKS[tr["attack"]](x, f, **hyper)  # noqa
@@ -355,7 +358,7 @@ def reference_readings(cell, seed: int, batches: list,
         # inside one jit XLA may skip a bf16 round trip (excess precision
         # is allowed), and the program rounds its stored params every step
         grad = jax.jit(lambda p, b: jax.value_and_grad(
-            lambda q: ref.loss(m, q, b, mode))(f32(p)))
+            lambda q: lm.loss(m, q, b, mode))(f32(p)))
 
         @jax.jit
         def coordwise(stack):

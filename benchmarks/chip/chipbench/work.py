@@ -1,6 +1,8 @@
-"""The chip's peaks and the work a step or a kernel must do, counted from
-shapes.  These counts are the benchmark's, not the program's: they stay
-the same whatever implements the step or the rule."""
+"""The chip's peaks and the work a kernel must do, counted from shapes.
+These counts are the benchmark's, not the program's: they stay the same
+whatever implements the rule.  A training step's FLOPs per token depend
+on the model, and are counted by the configuration's reference module
+(``train_flops_per_token``)."""
 from __future__ import annotations
 
 import json
@@ -18,26 +20,6 @@ def peaks(device_kind: str, bench_dir: str = BENCH_DIR) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"peaks.json (have {sorted(table)})")
     return table[device_kind]
-
-
-def matmul_params(model: dict) -> int:
-    """Weights that enter a matmul per token: every block's projections
-    and the output head; the input embedding (a gather) and the norms
-    are left out."""
-    d, hd = model["d_model"], model.get("head_dim") or (
-        model["d_model"] // model["num_heads"])
-    h, k, ff = model["num_heads"], model["num_kv_heads"], model["d_ff"]
-    attn = d * h * hd + 2 * d * k * hd + h * hd * d
-    mlp = 3 * d * ff if model.get("act", "silu") == "silu" else 2 * d * ff
-    return model["num_layers"] * (attn + mlp) + d * model["vocab_size"]
-
-
-def train_flops_per_token(model: dict, seq: int) -> float:
-    """Forward and backward FLOPs per token, recompute not counted:
-    6 N + 12 L H hd T (PaLM, arXiv:2204.02311, App. B)."""
-    hd = model.get("head_dim") or model["d_model"] // model["num_heads"]
-    return (6.0 * matmul_params(model)
-            + 12.0 * model["num_layers"] * model["num_heads"] * hd * seq)
 
 
 # the rules whose least work includes the pairwise Gram matrix

@@ -16,20 +16,18 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 REPO = Path(__file__).resolve().parents[2]
 BENCH = REPO / "benchmarks" / "chip"
+TINY = Path(__file__).resolve().parent / "tiny"
 sys.path.insert(0, str(BENCH))
 
 from chipbench import cli, spec, trace, traffic, work  # noqa: E402
 
-TINY_MODEL = {"num_layers": 4, "d_model": 128, "num_heads": 4,
-              "num_kv_heads": 2, "head_dim": 32, "d_ff": 256,
-              "vocab_size": 1024, "rope_theta": 10000.0, "norm_eps": 1e-5,
-              "dtype": "bfloat16"}
 TRAIN, SERVE = "train.sync.trimmed", "serve.steady"
 ASYNC = "train.async.krum"
 
@@ -52,10 +50,21 @@ def _bench():
         return json.load(fh)
 
 
+def tiny_model(family: str) -> dict:
+    """The tiny model of ``family`` (``tiny/<family>.json``) that the CPU
+    runs put in a configuration's place, its family named."""
+    path = TINY / f"{family}.json"
+    if not path.is_file():
+        raise FileNotFoundError(f"no tiny model of family {family!r}: "
+                                f"{path} is missing")
+    return {"family": family, **json.loads(path.read_text())}
+
+
 def tiny_root(tmp_path) -> Path:
     """A checkout holding BENCHMARK.json and a copy of the benchmark, with
-    the real cells' configurations cut to a tiny model and their mixes to
-    tiny sizes; the limits stay the real cells' own."""
+    the real cells' configurations cut to the tiny model of their own
+    family and their mixes to tiny sizes; the limits stay the real
+    cells' own."""
     root = tmp_path / "checkout"
     bench = root / "benchmarks" / "chip"
     shutil.copytree(BENCH, bench,
@@ -64,7 +73,7 @@ def tiny_root(tmp_path) -> Path:
     for c in b["configs"]:
         path = root / c["file"]
         cfg = json.loads(path.read_text())
-        cfg["model"] = dict(TINY_MODEL)
+        cfg["model"] = tiny_model(cfg["model"]["family"])
         if "seq_capacity" in cfg["deployment"]:
             cfg["deployment"].update(slot_buckets=[2, 4], seq_capacity=40)
         path.write_text(json.dumps(cfg))
@@ -113,6 +122,13 @@ def test_every_cell_resolves_to_its_files():
         cell = spec.load_cell(str(REPO), w["name"])
         assert cell.traffic["kind"] in ("train", "serve")
         assert cell.config["model"]["d_model"] == 768
+        assert cell.config["model"]["family"] in spec.known_families()
+        ref = cell.reference
+        assert (Path(ref.__file__).resolve()
+                == (REPO / cell.config["reference"]).resolve())
+        assert callable(ref.loss) and callable(ref.train_flops_per_token)
+        if cell.traffic["kind"] == "serve":
+            assert callable(ref.logits)
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert cell.per_layer
@@ -154,6 +170,76 @@ def test_new_mix_and_metric_are_found_by_name(tmp_path):
             == json.loads((BENCH / "traffic" / f"{SERVE}.json").read_text()))
     with pytest.raises(LookupError):
         spec.load_cell(str(root), "no.such.cell", bench_dir)
+
+
+def _set_config(root, name, edit):
+    """Rewrite configuration ``name`` of the checkout at ``root``."""
+    (entry,) = [c for c in _bench()["configs"] if c["name"] == name]
+    path = root / entry["file"]
+    cfg = json.loads(path.read_text())
+    edit(cfg)
+    path.write_text(json.dumps(cfg))
+
+
+def _no_family(cfg):
+    del cfg["model"]["family"]
+
+
+def _unknown_family(cfg):
+    cfg["model"]["family"] = "no_such_family"
+
+
+def _no_reference_file(cfg):
+    cfg["reference"] = "benchmarks/chip/refs/no_such_module.py"
+
+
+def _reference_without_loss(cfg):
+    cfg["reference"] = "benchmarks/chip/metrics/train_step_mfu.py"
+
+
+@pytest.mark.parametrize("edit, error", [
+    (_no_family, LookupError), (_unknown_family, ValueError),
+    (_no_reference_file, FileNotFoundError),
+    (_reference_without_loss, LookupError)])
+def test_configuration_without_family_or_reference_is_refused(
+        edit, error, tmp_path, capsys):
+    """``load_cell`` refuses the configuration before any device work,
+    naming its file, and the run prints no result line."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(REPO / "BENCHMARK.json", root / "BENCHMARK.json")
+    _set_config(root, "paper-100m.silo8", edit)
+    bench_dir = str(root / "benchmarks" / "chip")
+    with pytest.raises(error, match="paper-100m.silo8.json"):
+        spec.load_cell(str(root), TRAIN, bench_dir)
+    # the serving cell's configuration is untouched, and still loads
+    assert spec.load_cell(str(root), SERVE, bench_dir).reference.logits
+    capsys.readouterr()
+    rc = cli.main(["--workload", TRAIN, "--seed", "1", "--seconds", "1"],
+                  root=str(root), t_start=time.perf_counter(),
+                  gate=lambda chips: pytest.fail("the gate was reached"),
+                  bench_dir=bench_dir)
+    out, err = capsys.readouterr()
+    assert rc != 0 and out == "" and "paper-100m.silo8.json" in err
+
+
+def test_tiny_root_cuts_each_configuration_to_its_familys_tiny_model(
+        tmp_path):
+    root = tiny_root(tmp_path)
+    for c in _bench()["configs"]:
+        cfg = json.loads((root / c["file"]).read_text())
+        real = json.loads((REPO / c["file"]).read_text())["model"]
+        want = json.loads((TINY / f"{real['family']}.json").read_text())
+        assert cfg["model"] == {"family": real["family"], **want}
+        assert cfg["reference"] == json.loads(
+            (REPO / c["file"]).read_text())["reference"]
+
+
+def test_family_without_tiny_model_names_the_missing_file():
+    with pytest.raises(FileNotFoundError,
+                       match=str(TINY / "no_such_family.json")):
+        tiny_model("no_such_family")
 
 
 # ---------------------------------------------------------------------------
@@ -243,13 +329,29 @@ def test_multi_krum_selection_hand_made():
 
 
 def test_flops_per_token_hand_count():
-    m = json.loads((BENCH / "configs" / "paper-100m.silo8.json"
-                    ).read_text())["model"]
+    cell = spec.load_cell(str(REPO), TRAIN)
+    dense, m = cell.reference, cell.config["model"]
+    assert Path(dense.__file__).name == "dense_decoder.py"
     per_layer = (768 * 768 * 2 + 768 * 256 * 2 + 3 * 768 * 2048)
-    assert work.matmul_params(m) == 12 * per_layer + 768 * 32000
-    assert work.matmul_params(m) == 100_073_472
-    assert work.train_flops_per_token(m, 256) == (
+    assert dense.matmul_params(m) == 12 * per_layer + 768 * 32000
+    assert dense.matmul_params(m) == 100_073_472
+    assert dense.train_flops_per_token(m, 256) == (
         6 * 100_073_472 + 12 * 12 * 12 * 64 * 256)
+    assert dense.train_flops_per_token(m, 512) == (
+        6 * 100_073_472 + 12 * 12 * 12 * 64 * 512)
+
+
+def test_step_mfu_reads_the_runs_flops_per_token():
+    """``train_step_mfu`` takes the FLOPs per token that the harness
+    counted through the configuration's reference, whatever the model."""
+    read = spec.metric_reader("train_step_mfu")
+    peak = work.peaks("TPU v5 lite")
+    run = SimpleNamespace(kind="train", tokens_per_s=47_500.0,
+                          flops_per_token=1.0e9, peak=peak,
+                          model={"family": "ssm"}, seq=256)
+    assert read(run) == 100.0 * (1.0e9 * 47_500.0) / 197e12
+    assert read(SimpleNamespace(**dict(vars(run), kind="serve"))) is None
+    assert not hasattr(work, "train_flops_per_token")
 
 
 def test_aggregation_least_work_hand_count():
@@ -374,6 +476,35 @@ def _train_run_and_control(cell, tmp_path, capsys, monkeypatch):
 def test_train_run_is_correct_and_its_control_is_not(tmp_path, capsys,
                                                       monkeypatch):
     _train_run_and_control(TRAIN, tmp_path, capsys, monkeypatch)
+
+
+def test_reference_is_the_module_the_configuration_names(
+        tmp_path, capsys, monkeypatch):
+    """A copy of the dense reference under another path, the original
+    gone: the training cell runs through the copy and reads correct."""
+    root = tiny_root(tmp_path)
+    refs = root / "benchmarks" / "chip" / "refs"
+    copy = root / "benchmarks" / "chip" / "other_refs" / "dense_copy.py"
+    copy.parent.mkdir()
+    shutil.move(refs / "dense_decoder.py", copy)
+    _set_config(root, "paper-100m.silo8", lambda cfg: cfg.update(
+        reference="benchmarks/chip/other_refs/dense_copy.py"))
+    calls = []
+    load = spec.load_reference
+
+    def recording(*args, **kw):
+        mod = load(*args, **kw)
+        for name in ("loss", "train_flops_per_token"):
+            def wrapped(*a, _f=getattr(mod, name), _n=name, **k):
+                calls.append((mod.__file__, _n))
+                return _f(*a, **k)
+            setattr(mod, name, wrapped)
+        return mod
+    monkeypatch.setattr(spec, "load_reference", recording)
+    res, err = run_cell(root, TRAIN, capsys, monkeypatch)
+    assert res["correct"] is True, err[-2000:]
+    assert {n for _, n in calls} == {"loss", "train_flops_per_token"}
+    assert {Path(f).resolve() for f, _ in calls} == {copy.resolve()}
 
 
 def test_async_train_run_is_correct_and_its_control_is_not(
