@@ -774,12 +774,20 @@ class AggregatorSpec:
         rules dequantize INSIDE the tile (no dequantized (n, P) copy is
         materialized — jaxpr-gated by tests/test_kernels_parity.py);
         other rules dequantize at engine level with a one-time
-        warning."""
+        warning.
+
+        ``stack`` may also be a mixed-dtype tree's SEGMENTED arena, a
+        tuple of (n, P_k) arrays (:meth:`FlatPlan.ravel`); the result is
+        then the tuple of per-segment (P_k,) fp32 vectors (see
+        :func:`_flat_segments_vec`)."""
         d = get_aggregator_def(self.name)
         if not self.flat_capable:
             raise ValueError(
                 f"{self.describe()} (impl={self.impl}) has no flat path — "
                 "check spec.flat_capable before routing the arena")
+        if isinstance(stack, tuple):
+            return _flat_segments_vec(self, d, stack, mask, weights, state,
+                                      scale)
         if d.flat_fn is not None:
             return d.flat_fn(self, stack, mask, weights, state, scale)
         if mask is None and weights is None:
@@ -1263,6 +1271,22 @@ def _arrived_coord_vec(spec, xf, mask):
         return ref.arrived_mean_closest_ref(xf, mask, "median", spec.f)
     b = trim_count(xf.shape[0], spec.f, spec.hp("beta"))
     return ref.masked_stat_ref(xf, mask, None, "trimmed_mean", b=b)
+
+
+def _flat_segments_vec(spec, d, segs, mask, weights, state, qscale):
+    """Segmented arena (one (n, P_k) array per dtype) -> tuple of (P_k,)
+    fp32.  A coordinate-wise rule never couples columns, so each segment
+    is aggregated exactly as a uniform arena of its dtype is (the bf16
+    segment keeps its kernel path).  Every other rule couples a row's
+    columns (distances, norms, fixed points) and runs on the segments as
+    a tree of one leaf per dtype: the tree engine, whose fused Gram sums
+    the per-segment partials in fp32 before it selects."""
+    if qscale is not None:
+        raise ValueError("a quantized arena is one (n, P) array of codes")
+    if d.caps.coordwise and not spec.stateful:
+        return tuple(spec.aggregate_flat(s, mask, weights) for s in segs)
+    agg = spec.aggregate(segs, mask=mask, weights=weights, state=state)
+    return tuple(a.astype(jnp.float32) for a in agg)
 
 
 def _flat_sync_vec(spec, d, stack, state, qscale=None):

@@ -21,10 +21,16 @@ A :class:`FlatPlan` hoists all of that to plan time:
   tree exactly once, at optimizer-apply — never inside the aggregation
   dispatch.
 
-The arena dtype is the tree's uniform leaf dtype when one exists (so
-``agg_dtype`` exchange-compression survives the flattening) and fp32
-otherwise; per-coordinate arithmetic is unchanged either way, so the flat
-pipeline is bit-for-bit with the per-call ravel it replaces.
+The arena keeps each leaf's dtype.  A tree of one dtype gets one (n, P)
+array of that dtype (so ``agg_dtype`` exchange-compression survives the
+flattening), bit-for-bit with the per-call ravel it replaces.  A tree
+whose leaves mix dtypes (Mamba-2's float32 ``A_log``/``dt_bias``/``D``
+beside bf16 matrices, an fp32 MoE router) gets a *segmented* arena: a
+tuple of (n, P_k) arrays, one per dtype in order of first appearance,
+each laid out exactly as a uniform arena of that dtype.  No coordinate
+is widened into or narrowed by another leaf's dtype, and the arena takes
+the bytes its leaves take.  A single array is always in tree order and a
+tuple always in segment order; :meth:`FlatPlan.unravel` reads either.
 """
 from __future__ import annotations
 
@@ -36,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.counters import inc
+
 
 @dataclass(frozen=True)
 class FlatPlan:
@@ -43,8 +51,10 @@ class FlatPlan:
 
     ``shapes``/``dtypes`` describe the per-leaf TRAILING dims (agent axis
     stripped); ``offsets[i]:offsets[i] + sizes[i]`` is leaf i's slice of
-    the (n, P) arena; ``total`` is P.  Frozen and hashable, so plans pass
-    freely through jit closures as statics."""
+    the (n, P) arena; ``total`` is P.  ``segments`` lists, per dtype in
+    order of first appearance, ``(dtype name, leaf indices)``;
+    ``seg_offsets[i]`` is leaf i's offset inside its segment.  Frozen and
+    hashable, so plans pass freely through jit closures as statics."""
     treedef: Any
     shapes: tuple
     dtypes: tuple
@@ -52,6 +62,8 @@ class FlatPlan:
     sizes: tuple
     total: int
     uniform_dtype: Optional[Any]
+    segments: tuple
+    seg_offsets: tuple
 
     @staticmethod
     def for_tree(tree) -> "FlatPlan":
@@ -73,33 +85,53 @@ class FlatPlan:
     @property
     def arena_dtype(self):
         """Dtype of the (n, P) arena :meth:`ravel` builds: the uniform
-        leaf dtype when there is one (exchange compression survives),
-        fp32 otherwise (the dense engine contract)."""
-        return (jnp.dtype(self.uniform_dtype) if self.uniform_dtype
-                else jnp.float32)
+        leaf dtype, or None for a tree of mixed dtypes, whose arena is one
+        segment per dtype (:attr:`segments`)."""
+        return jnp.dtype(self.uniform_dtype) if self.uniform_dtype else None
 
     def ravel(self, tree, dtype=None):
-        """(pytree with leading n) -> one (n, P) arena (ONE concatenate)."""
+        """(pytree with leading n) -> its arena: one (n, P) array in
+        ``dtype`` (default: the uniform leaf dtype) with ONE concatenate,
+        or, for a mixed-dtype tree and no ``dtype``, the tuple of its
+        (n, P_k) segments, one concatenate each."""
         leaves = jax.tree.leaves(tree)
         n = leaves[0].shape[0]
+        if dtype is None and self.uniform_dtype is None:
+            inc("core.flat.segments", len(self.segments))
+            return tuple(
+                jnp.concatenate([leaves[i].reshape(n, -1) for i in idx],
+                                axis=1)
+                for _, idx in self.segments)
+        inc("core.flat.segments")
         dt = jnp.dtype(dtype) if dtype is not None else self.arena_dtype
         return jnp.concatenate(
             [l.reshape(n, -1).astype(dt) for l in leaves], axis=1)
 
+    def _slices(self, arena):
+        """(array, offset, size) of each leaf along ``arena``'s last axis:
+        tree-order offsets for one array, segment offsets for a tuple."""
+        if not isinstance(arena, tuple):
+            return [(arena, o, s) for o, s in zip(self.offsets, self.sizes)]
+        seg_of = {i: k for k, (_, idx) in enumerate(self.segments)
+                  for i in idx}
+        return [(arena[seg_of[i]], self.seg_offsets[i], s)
+                for i, s in enumerate(self.sizes)]
+
     def unravel(self, vec):
-        """(P,) -> single-agent pytree (leaf dtypes restored)."""
-        out = [jax.lax.slice(vec, (o,), (o + s,)).reshape(shp).astype(dt)
-               for o, s, shp, dt in zip(self.offsets, self.sizes,
-                                        self.shapes, self.dtypes)]
+        """(P,) or a tuple of per-segment (P_k,) -> single-agent pytree
+        (leaf dtypes restored)."""
+        out = [jax.lax.slice(v, (o,), (o + s,)).reshape(shp).astype(dt)
+               for (v, o, s), shp, dt in zip(self._slices(vec), self.shapes,
+                                             self.dtypes)]
         return jax.tree.unflatten(self.treedef, out)
 
     def unravel_stack(self, arena):
-        """(n, P) -> pytree with leading n (leaf dtypes restored)."""
-        n = arena.shape[0]
-        out = [jax.lax.slice(arena, (0, o), (n, o + s))
-               .reshape((n,) + shp).astype(dt)
-               for o, s, shp, dt in zip(self.offsets, self.sizes,
-                                        self.shapes, self.dtypes)]
+        """(n, P) or a tuple of (n, P_k) segments -> pytree with leading n
+        (leaf dtypes restored)."""
+        out = [jax.lax.slice(a, (0, o), (a.shape[0], o + s))
+               .reshape((a.shape[0],) + shp).astype(dt)
+               for (a, o, s), shp, dt in zip(self._slices(arena),
+                                             self.shapes, self.dtypes)]
         return jax.tree.unflatten(self.treedef, out)
 
 
@@ -157,10 +189,25 @@ def _plan(treedef, shapes, dtypes) -> FlatPlan:
     sizes = tuple(int(np.prod(s, dtype=np.int64)) for s in shapes)
     offsets = tuple(int(o) for o in np.cumsum((0,) + sizes)[:-1])
     uniform = dtypes[0] if len(set(dtypes)) == 1 else None
+    by_dt: dict = {}
+    for i, dt in enumerate(dtypes):
+        by_dt.setdefault(dt, []).append(i)
+    segments = tuple((dt, tuple(idx)) for dt, idx in by_dt.items())
+    seg_offsets = [0] * len(sizes)
+    for _, idx in segments:
+        at = 0
+        for i in idx:
+            seg_offsets[i], at = at, at + sizes[i]
     return FlatPlan(treedef=treedef, shapes=shapes, dtypes=dtypes,
                     offsets=offsets, sizes=sizes,
-                    total=int(sum(sizes)), uniform_dtype=uniform)
+                    total=int(sum(sizes)), uniform_dtype=uniform,
+                    segments=segments, seg_offsets=tuple(seg_offsets))
 
 
-__all__ = ["FlatPlan", "QUANT_DTYPES", "quantize_rows", "dequantize_rows",
-           "fake_quantize"]
+def arena_rows(arena, idx):
+    """Rows ``idx`` of an arena, one array or a tuple of segments."""
+    return jax.tree.map(lambda a: a[idx], arena)
+
+
+__all__ = ["FlatPlan", "QUANT_DTYPES", "arena_rows", "quantize_rows",
+           "dequantize_rows", "fake_quantize"]

@@ -5,6 +5,15 @@ dense (MXU-friendly) attention-like matmuls through the 1-semiseparable decay
 mask; inter-chunk terms pass an (h, p, n) recurrent state.  Decode is the O(1)
 recurrent update.  The causal depthwise conv (kernel 4) over (x, B, C) is kept,
 with a conv ring state for decode.
+
+Under training the forward and backward carry ``jax.named_scope``s that a
+profile shows inside the step's ``grads`` scope: ``ssm.in_proj``,
+``ssm.conv``, ``ssd.diag`` (intra-chunk), ``ssd.states`` (per-chunk final
+states), ``ssd.carry`` (the inter-chunk recurrence), ``ssd.off`` (the
+carried state's output) and ``ssm.out`` (skip, gate, norm, out_proj).
+Each trace of :func:`ssd_chunked` counts ``models.ssd.chunked``, and
+``models.ssd.padded`` when the sequence is not a multiple of the chunk
+(:mod:`repro.obs.counters`).
 """
 from __future__ import annotations
 
@@ -12,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import dense_init, rms_norm
+from repro.obs.counters import count_trace
 
 
 # ---------------------------------------------------------------------------
@@ -62,10 +72,12 @@ def ssd_chunked(x, a, B, C, chunk: int, initial_state=None):
     b, t, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     t_orig = t
+    count_trace("models.ssd.chunked")
     pad = (-t) % chunk
     if pad:
         # zero-pad to a chunk multiple: zero x/B contribute nothing to the
         # state; a=0 (decay 1) carries the state through the padding
+        count_trace("models.ssd.padded")
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -80,38 +92,42 @@ def ssd_chunked(x, a, B, C, chunk: int, initial_state=None):
     Cr = jnp.repeat(C.reshape(b, c, chunk, g, n), rep, axis=3)
 
     a_cs = jnp.cumsum(ar, axis=-1)                              # (b,h,c,l)
-    L = jnp.exp(_segsum(ar))                                    # (b,h,c,l,l)
 
     # intra-chunk (diagonal blocks)
-    y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp",
-                        Cr, Br, L.astype(Cr.dtype), xr,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("ssd.diag"):
+        L = jnp.exp(_segsum(ar))                                # (b,h,c,l,l)
+        y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp",
+                            Cr, Br, L.astype(Cr.dtype), xr,
+                            preferred_element_type=jnp.float32)
 
     # per-chunk final states
-    decay_states = jnp.exp(a_cs[..., -1:] - a_cs)               # (b,h,c,l)
-    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn",
-                        Br, decay_states.astype(Br.dtype), xr,
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope("ssd.states"):
+        decay_states = jnp.exp(a_cs[..., -1:] - a_cs)           # (b,h,c,l)
+        states = jnp.einsum("bclhn,bhcl,bclhp->bchpn",
+                            Br, decay_states.astype(Br.dtype), xr,
+                            preferred_element_type=jnp.float32)
 
     # inter-chunk recurrence over c (small: t/chunk) via segsum matmul
-    if initial_state is None:
-        initial_state = jnp.zeros((b, h, p, n), states.dtype)
-    states = jnp.concatenate(
-        [initial_state[:, None].astype(states.dtype), states], axis=1)
-    chunk_decay = a_cs[..., -1]                                 # (b,h,c)
-    padded = jnp.pad(chunk_decay, ((0, 0), (0, 0), (1, 0)))
-    decay_chunk = jnp.exp(_segsum(padded))                      # (b,h,c+1,c+1)
-    decay_chunk = jnp.where(jnp.isfinite(decay_chunk), decay_chunk, 0.0)
-    new_states = jnp.einsum("bhzc,bchpn->bzhpn",
-                            decay_chunk.astype(states.dtype), states)
-    carried, final_state = new_states[:, :-1], new_states[:, -1]
+    with jax.named_scope("ssd.carry"):
+        if initial_state is None:
+            initial_state = jnp.zeros((b, h, p, n), states.dtype)
+        states = jnp.concatenate(
+            [initial_state[:, None].astype(states.dtype), states], axis=1)
+        chunk_decay = a_cs[..., -1]                             # (b,h,c)
+        padded = jnp.pad(chunk_decay, ((0, 0), (0, 0), (1, 0)))
+        decay_chunk = jnp.exp(_segsum(padded))              # (b,h,c+1,c+1)
+        decay_chunk = jnp.where(jnp.isfinite(decay_chunk), decay_chunk, 0.0)
+        new_states = jnp.einsum("bhzc,bchpn->bzhpn",
+                                decay_chunk.astype(states.dtype), states)
+        carried, final_state = new_states[:, :-1], new_states[:, -1]
 
     # contribution of carried state to each chunk position
-    state_decay = jnp.exp(a_cs)                                 # (b,h,c,l)
-    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp",
-                       Cr, carried.astype(Cr.dtype),
-                       state_decay.astype(Cr.dtype),
-                       preferred_element_type=jnp.float32)
+    with jax.named_scope("ssd.off"):
+        state_decay = jnp.exp(a_cs)                             # (b,h,c,l)
+        y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp",
+                           Cr, carried.astype(Cr.dtype),
+                           state_decay.astype(Cr.dtype),
+                           preferred_element_type=jnp.float32)
 
     y = (y_diag + y_off).reshape(b, t, h, p)[:, :t_orig]
     return y.astype(x.dtype), final_state
@@ -173,9 +189,11 @@ def mamba_block(cfg, params, x):
     di, g, n = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
     h, p = cfg.ssm_num_heads, cfg.ssm_head_dim
 
-    zxbcdt = x @ params["in_proj"]
-    z, xBC, dt = _split_proj(cfg, zxbcdt)
-    xBC = causal_conv(xBC, params["conv_w"], params["conv_b"])
+    with jax.named_scope("ssm.in_proj"):
+        zxbcdt = x @ params["in_proj"]
+        z, xBC, dt = _split_proj(cfg, zxbcdt)
+    with jax.named_scope("ssm.conv"):
+        xBC = causal_conv(xBC, params["conv_w"], params["conv_b"])
     xs = xBC[..., :di].reshape(Bsz, T, h, p)
     Bm = xBC[..., di:di + g * n].reshape(Bsz, T, g, n)
     Cm = xBC[..., di + g * n:].reshape(Bsz, T, g, n)
@@ -184,11 +202,13 @@ def mamba_block(cfg, params, x):
     A = -jnp.exp(params["A_log"])                       # (h,)
     y, state = ssd_chunked(xs * dt[..., None].astype(xs.dtype),
                            dt * A, Bm, Cm, cfg.ssm_chunk)
-    y = y + (params["D_skip"][:, None] * xs.astype(jnp.float32)).astype(y.dtype)
-    y = y.reshape(Bsz, T, di)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 params["norm_scale"], cfg.norm_eps)
-    return y @ params["out_proj"], state
+    with jax.named_scope("ssm.out"):
+        y = y + (params["D_skip"][:, None]
+                 * xs.astype(jnp.float32)).astype(y.dtype)
+        y = y.reshape(Bsz, T, di)
+        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                     params["norm_scale"], cfg.norm_eps)
+        return y @ params["out_proj"], state
 
 
 def init_ssm_cache(cfg, batch: int, dtype):

@@ -58,8 +58,8 @@ import numpy as np
 
 from repro.checkpoint import save
 from repro.core.aggregators import tree_stack_ravel, tree_where_agents
-from repro.core.flat import (FlatPlan, QUANT_DTYPES, fake_quantize,
-                             quantize_rows)
+from repro.core.flat import (FlatPlan, QUANT_DTYPES, arena_rows,
+                             fake_quantize, quantize_rows)
 from repro.obs.counters import count_trace
 from repro.obs.spans import (TRAIN_BATCH, TRAIN_CHECKPOINT, TRAIN_DISPATCH,
                              TRAIN_LOG, TRAIN_STEP, span, step_span)
@@ -247,6 +247,11 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
                 sent = jax.tree.map(
                     lambda l: l.astype(jnp.dtype(bz.agg_dtype)), sent)
             plan = FlatPlan.for_tree(sent)
+            # a mixed-dtype tree rides the arena as one segment per dtype,
+            # except where the rule's state or the coded fallback is laid
+            # out over one (n, P) array
+            flat = use_flat and (quant or plan.uniform_dtype is not None
+                                 or (not stateful and fallback_r == 0))
             codes = qs = arena = None
             if quant:
                 # quantize the wire: codes + per-row fp32 scale.  Codes
@@ -264,8 +269,7 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
                 else:
                     sent = plan.unravel_stack(fake_quantize(arena, qdt))
                     arena = None
-            elif (bz.draco_r == 0 and use_flat
-                  and plan.uniform_dtype is not None):
+            elif bz.draco_r == 0 and flat:
                 # ONE ravel into the (n, P) arena at the communication
                 # boundary; the single unravel happens below, at
                 # optimizer-apply
@@ -290,16 +294,15 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
                 else:
                     agg = tree_draco_aggregate(sent, bz.draco_r,
                                                mask=mask, groups=groups)
-            elif use_flat and (quant or plan.uniform_dtype is not None):
-                # the (n, P) arena raveled above: the quorum mask and
-                # staleness discounts enter the masked kernels as traced
-                # operands.  Mixed-dtype trees keep the tree path: a fp32
-                # arena would impute masked rows without each leaf's
-                # native rounding (not bitwise) — except under quantized
-                # exchange, which erases leaf dtypes anyway.
+            elif flat:
+                # the arena raveled above: the quorum mask and staleness
+                # discounts enter the masked kernels as traced operands
+                # (a segmented arena: per segment for the coordinate-wise
+                # rules, so each leaf keeps its native rounding)
                 wire = codes if codes is not None else arena
                 if bucket is not None:
-                    rows, rmask, rw = wire[roster_idx], w_b > 0.0, w_b
+                    rows, rmask, rw = (arena_rows(wire, roster_idx),
+                                       w_b > 0.0, w_b)
                     rqs = qs[roster_idx] if qs is not None else None
                 else:
                     rows, rmask, rw = wire, mask, contrib_w
@@ -358,9 +361,7 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
             if bz.draco_r > 0:
                 sel = particip          # per-group votes: delivery shares
             elif bucket is not None:
-                stack_b = (arena[roster_idx]
-                           if use_flat and (quant
-                                            or plan.uniform_dtype is not None)
+                stack_b = (arena_rows(arena, roster_idx) if flat
                            else jax.tree.map(lambda l: l[roster_idx], sent))
                 sel_b = spec.selection_weights(stack_b, mask=w_b > 0.0,
                                                weights=w_b, state=st)
@@ -370,10 +371,7 @@ def make_async_step(cfg, bz, optimizer, fallback_r: int = 0,
                     # quorum missed -> the coded vote aggregated instead
                     sel = jnp.where(use_coded, particip, sel)
             else:
-                stack = (arena
-                         if use_flat and (quant
-                                          or plan.uniform_dtype is not None)
-                         else sent)
+                stack = arena if flat else sent
                 sel = spec.selection_weights(stack, mask=mask,
                                              weights=contrib_w, state=st)
                 if fallback_r > 0:

@@ -31,8 +31,8 @@ import jax.numpy as jnp
 
 from repro.core.aggregators import AggregatorSpec, make_spec
 from repro.core.attacks import get_attack, make_byzantine_mask
-from repro.core.flat import (FlatPlan, QUANT_DTYPES, fake_quantize,
-                             quantize_rows)
+from repro.core.flat import (FlatPlan, QUANT_DTYPES, arena_rows,
+                             fake_quantize, quantize_rows)
 from repro.core.momentum import worker_momentum
 from repro.obs.counters import count_trace
 from repro.core.redundancy.coding import coding_groups, tree_draco_aggregate
@@ -283,16 +283,14 @@ def make_train_step(cfg, bz: ByzantineConfig, optimizer,
                 else:
                     grads = plan.unravel_stack(
                         fake_quantize(arena, jnp.dtype(bz.agg_dtype)))
-            elif (bz.draco_r == 0 and use_flat
-                  and plan.uniform_dtype is not None):
+            elif bz.draco_r == 0 and use_flat:
                 # zero-copy: ONE ravel into the (n, P) arena here, the
                 # aggregation runs on the arena, and the single unravel
                 # below happens at optimizer-apply — plan offsets are
                 # precomputed (FlatPlan is cached per tree structure), so
-                # the dispatch itself moves no model-sized memory.
-                # Mixed-dtype trees keep the tree path: flattening them
-                # would impute masked rows at fp32 instead of each leaf's
-                # native rounding (not bitwise).
+                # the dispatch itself moves no model-sized memory.  A
+                # mixed-dtype tree's arena is one segment per dtype, so
+                # every coordinate keeps its leaf's dtype.
                 arena = plan.ravel(grads)
         vec = None
         with jax.named_scope("aggregate"):
@@ -319,9 +317,9 @@ def make_train_step(cfg, bz: ByzantineConfig, optimizer,
                                               scale=qs[roster_idx])
                 else:
                     vec = spec.aggregate_flat(codes, scale=qs)
-            elif use_flat and plan.uniform_dtype is not None:
+            elif use_flat:
                 if bucket is not None:
-                    vec = spec.aggregate_flat(arena[roster_idx],
+                    vec = spec.aggregate_flat(arena_rows(arena, roster_idx),
                                               mask=roster_valid)
                 else:
                     vec = spec.aggregate_flat(arena)
@@ -369,9 +367,8 @@ def make_train_step(cfg, bz: ByzantineConfig, optimizer,
                 # quantized runs attribute weights on the PRE-quantization
                 # f32 arena (it exists anyway — it is what was quantized);
                 # observability must not add a dequantized (n, P) copy
-                flat_stack = codes is not None or (
-                    use_flat and plan.uniform_dtype is not None)
-                stack = (arena[roster_idx] if flat_stack
+                flat_stack = codes is not None or use_flat
+                stack = (arena_rows(arena, roster_idx) if flat_stack
                          else jax.tree.map(lambda l: l[roster_idx], grads))
                 sel_b = spec.selection_weights(stack, mask=roster_valid)
                 sel = jnp.zeros((n,), jnp.float32).at[roster_idx].add(
@@ -379,8 +376,7 @@ def make_train_step(cfg, bz: ByzantineConfig, optimizer,
                 m_full = jnp.zeros((n,), bool).at[roster_idx].max(
                     roster_valid)
             else:
-                flat_stack = codes is not None or (
-                    use_flat and plan.uniform_dtype is not None)
+                flat_stack = codes is not None or use_flat
                 stack = arena if flat_stack else grads
                 sel = spec.selection_weights(stack)
                 m_full = jnp.ones((n,), bool)

@@ -536,6 +536,100 @@ def test_aggregate_flat_matches_tree_engine(rule, mode, dtype):
                                           err_msg=f"{rule}/{impl}")
 
 
+def _mixed_tree(n, seed):
+    """bf16 leaves beside f32 ones, interleaved in tree order (as
+    Mamba-2's float32 A_log / dt_bias / D sit among bf16 matrices)."""
+    g = data(n, 640, jnp.float32, seed)
+    return {"a": g[:, :123].reshape(n, 3, 41).astype(jnp.bfloat16),
+            "b": {"A_log": g[:, 123:130],
+                  "c": g[:, 130:630].astype(jnp.bfloat16),
+                  "d": g[:, 630:].reshape(n, 2, 5)}}
+
+
+@pytest.mark.parametrize("mode", ["plain", "masked"])
+@pytest.mark.parametrize("attack", ["none", "alie"])
+@pytest.mark.parametrize("rule", RULES)
+def test_segmented_arena_per_segment(rule, attack, mode):
+    """A mixed-dtype tree's arena is one segment per dtype, each in its
+    leaves' own dtype and no larger than they are.  A coordinate-wise
+    rule gives, segment by segment and bit for bit, what today's uniform
+    arena of that segment's leaves gives; a rule that couples a row's
+    columns gives what the tree engine gives on the whole tree."""
+    from repro.core.attacks import get_attack, make_byzantine_mask
+    from repro.core.aggregators import get_aggregator_def
+    from repro.training.step import tree_attack
+    n = 9
+    tree = _mixed_tree(n, 11)
+    if attack != "none":
+        tree = tree_attack(get_attack(attack, z=1.5), jax.random.PRNGKey(3),
+                           tree, make_byzantine_mask(n, F))
+    mask, w = mode_args("plain" if mode == "plain" else "weighted", n, 7)
+    plan = FlatPlan.for_tree(tree)
+    assert plan.uniform_dtype is None and plan.arena_dtype is None
+    segs = plan.ravel(tree)
+    leaves = jax.tree.leaves(tree)
+    assert isinstance(segs, tuple) and len(segs) == 2
+    for (dt, idx), seg in zip(plan.segments, segs):
+        assert seg.dtype == jnp.dtype(dt)
+        assert all(leaves[i].dtype == seg.dtype for i in idx)
+    assert (sum(x.nbytes for x in segs)
+            == sum(l.nbytes for l in leaves))
+    coordwise = get_aggregator_def(rule).caps.coordwise
+    for impl in ("pallas", "gather"):
+        spec = make_spec(rule, f=F, impl=impl, n=n, **HYPER.get(rule, {}))
+        vecs = spec.aggregate_flat(segs, mask=mask, weights=w)
+        got = plan.unravel(vecs)
+        for x, l in zip(jax.tree.leaves(got), leaves):
+            assert x.dtype == l.dtype and x.shape == l.shape[1:]
+        if coordwise:
+            for (_, idx), vec in zip(plan.segments, vecs):
+                sub = [leaves[i] for i in idx]
+                uni = FlatPlan.for_tree(sub)
+                want = spec.aggregate_flat(uni.ravel(sub), mask=mask,
+                                           weights=w)
+                np.testing.assert_array_equal(
+                    np.asarray(vec), np.asarray(want),
+                    err_msg=f"{rule}/{impl}")
+        else:
+            want = spec.aggregate(tree, mask=mask, weights=w)
+            for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                np.testing.assert_allclose(
+                    np.asarray(x, np.float32), np.asarray(y, np.float32),
+                    rtol=1e-2, atol=1e-3, err_msg=f"{rule}/{impl}")
+
+
+def test_uniform_arena_is_one_array_bit_for_bit():
+    """A tree of one dtype keeps today's arena: one (n, P) array of that
+    dtype, equal bit for bit to the concatenation of its leaves, one
+    segment counted per ravel; the same unravel reads it as an array or
+    as a one-segment tuple."""
+    from repro.obs import counters
+    n = 6
+    g = data(n, 300, jnp.bfloat16, 4)
+    tree = {"a": g[:, :120].reshape(n, 4, 30), "b": g[:, 120:]}
+    plan = FlatPlan.for_tree(tree)
+    assert plan.segments == (("bfloat16", (0, 1)),)
+    assert plan.arena_dtype == jnp.bfloat16
+    before = counters.snapshot()
+    arena = plan.ravel(tree)
+    assert counters.counter_delta(before) == {"core.flat.segments": 1}
+    assert not isinstance(arena, tuple) and arena.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(arena, np.float32),
+        np.asarray(jnp.concatenate([l.reshape(n, -1) for l in
+                                    jax.tree.leaves(tree)], axis=1),
+                   np.float32))
+    vec = arena[2].astype(jnp.float32)
+    for x, y in zip(jax.tree.leaves(plan.unravel(vec)),
+                    jax.tree.leaves(plan.unravel((vec,)))):
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
+    mixed = FlatPlan.for_tree(_mixed_tree(n, 2))
+    before = counters.snapshot()
+    mixed.ravel(_mixed_tree(n, 2))
+    assert counters.counter_delta(before) == {"core.flat.segments": 2}
+
+
 def test_flat_capability_boundaries():
     from repro.core.aggregators import clipped
     assert not make_spec("trimmed_mean", f=1, impl="fused").flat_capable

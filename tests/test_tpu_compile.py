@@ -1,4 +1,4 @@
-"""Compile the Pallas kernels and the paper-100m train steps for a TPU v5e
+"""Compile the Pallas kernels and the model train steps for a TPU v5e
 chip described here, not attached (no chip needed).
 
 Interpret mode, which the rest of the suite runs, evaluates the kernel
@@ -7,7 +7,8 @@ chip's compiler (Mosaic) accepts, nor the compiled TILE_D tiling.  These
 cases do: every kernel the dispatch tables, ``FLAT_SELF_KERNELED`` and
 the coded path reach, compiled at n in {8, 16} and P = 2**20, must lower
 to a ``tpu_custom_call``; the whole paper-100m train steps must compile
-and fit one chip's 16 GiB.
+and fit one chip's 16 GiB, and the mamba2-130m step, whose arena is one
+segment per dtype, 13 GiB.
 
 The topology is described inside a module fixture, never at import time:
 only one process may hold the TPU library, and every test worker imports
@@ -209,3 +210,44 @@ def test_paper_100m_train_step_fits_one_v5e(one_chip, compiled_kernels,
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert total < HBM_BYTES, f"{path}: {total / 2**30:.2f} GiB"
+
+
+def test_mamba2_130m_train_step_fits_one_v5e(one_chip, compiled_kernels):
+    """The ``train.sync.trimmed.mamba2`` step at the published widths
+    (24 SSD layers, seq 2048, per-agent batch 1, n=8, f=2, remat, AdamW,
+    ALIE vs trimmed_mean): its tree mixes 1,728 float32 parameters into
+    128,981,760 bf16 ones, and the arena keeps each leaf's dtype — a bf16
+    (8, 128,981,760) segment on the lane-dense ``coord_stat`` and an f32
+    (8, 1,728) one on the tiled path, no f32 (8, P) arena — so the step
+    fits in 13 GiB (15.65 GiB with the arena promoted to f32)."""
+    from repro.configs import get_config
+    from repro.core.aggregators import make_spec
+    from repro.obs import counters
+    from repro.optim import adamw, constant
+    from repro.training import ByzantineConfig, make_train_step
+    cfg, n = get_config("mamba2-130m"), 8
+    opt = adamw(constant(3e-4), b1=0.9, b2=0.95)
+    params, opt_state, batch, key = _step_shapes(one_chip, cfg, n, 2048, 1,
+                                                 opt)
+    bz = ByzantineConfig(n_agents=n, f=F, attack="alie",
+                         attack_hyper={"z": 1.5}, remat=True,
+                         aggregator=make_spec("trimmed_mean", f=F, n=n,
+                                              impl="auto"))
+    step = jax.jit(make_train_step(cfg, bz, opt), donate_argnums=(0, 1, 2))
+    before = counters.snapshot()
+    compiled = step.lower(params, opt_state, None, batch, key).compile()
+    moved = counters.counter_delta(before)
+    assert moved.get("core.flat.segments") == 2, moved
+    assert moved.get("kernels.coord_stat.lane_dense") == 1, moved
+    assert moved.get("kernels.coord_stat.tiled") == 1, moved
+    text = compiled.as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 2 and all(c.startswith("%coord_stat")
+                                   for c in calls), calls
+    assert "bf16[8,128981760]" in text
+    assert "f32[8,128983488]" not in text
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 13 * 2**30, f"{total / 2**30:.2f} GiB"
